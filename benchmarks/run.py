@@ -17,6 +17,7 @@ Prints ``name,us_per_call,derived`` CSV lines and writes the full report
 to results/bench_report.json.  The batched module additionally emits
 results/BENCH_batched.json (dense vs owner-sorted-CSR docs/s per batch
 size + tape coverage) for machine-readable perf tracking across PRs.
+Every selected module runs; the harness exits non-zero if any raised.
 """
 
 from __future__ import annotations
@@ -24,8 +25,11 @@ from __future__ import annotations
 import json
 import sys
 import time
+import traceback
 from pathlib import Path
 from typing import Dict
+
+from repro.launch.compile_cache import enable_compile_cache
 
 RESULTS = Path(__file__).resolve().parents[1] / "results"
 
@@ -58,8 +62,10 @@ def main() -> None:
         ("serve_load", serve_load),
         ("roofline", roofline),
     ]
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     report: Dict[str, object] = {}
+    failed = []
     print("name,us_per_call,derived")
     for name, mod in modules:
         if only and name != only:
@@ -68,11 +74,15 @@ def main() -> None:
         try:
             for line in mod.run(report):
                 print(line)
-        except Exception as exc:  # noqa: BLE001 -- keep the harness going
+        except Exception as exc:  # noqa: BLE001 -- run the rest, then fail
+            traceback.print_exc()
             print(f"{name}/ERROR,0,{type(exc).__name__}:{exc}")
+            failed.append(name)
         print(f"{name}/_elapsed,{(time.time()-t0)*1e6:.0f},seconds={time.time()-t0:.1f}")
     RESULTS.mkdir(parents=True, exist_ok=True)
     (RESULTS / "bench_report.json").write_text(json.dumps(report, indent=2, default=str))
+    if failed:
+        sys.exit(f"benchmark modules raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

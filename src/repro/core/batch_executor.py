@@ -358,12 +358,19 @@ class BatchValidator:
             t0 = time.perf_counter()
         # first call under a new shape pays the jit trace: attribute its
         # whole wall time to compile, steady-state launches to execute
-        with _phase("executor.compile" if new_shape else "executor.execute"):
-            with _span("executor.launch"):
-                valid, in_depth, frontier = self._fn(cols, jnp.asarray(ids))
-                valid = np.asarray(valid)  # forces device sync inside the span
-                in_depth = np.asarray(in_depth)
-                frontier = np.asarray(frontier)
+        try:
+            with _phase("executor.compile" if new_shape else "executor.execute"):
+                with _span("executor.launch"):
+                    valid, in_depth, frontier = self._fn(cols, jnp.asarray(ids))
+                    valid = np.asarray(valid)  # forces device sync inside the span
+                    in_depth = np.asarray(in_depth)
+                    frontier = np.asarray(frontier)
+        except Exception:
+            # a shape whose first launch failed has not compiled: the
+            # next launch under it is a first launch again
+            if new_shape:
+                self._seen_shapes.discard(shape)
+            raise
         if m is not None:
             self._m_launches.inc()
             self._m_launch_seconds.inc(time.perf_counter() - t0)
@@ -407,8 +414,9 @@ class BatchValidator:
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, Dict[int, str]]:
         """:meth:`validate_ex` with per-document launch-fault containment.
 
-        A launch that raises (device error, injected ``"launch"`` fault)
-        is bisected: rows are split in half and relaunched recursively
+        A launch that raises (device error on a shape that has already
+        compiled, injected ``"launch"`` fault) is bisected: rows are
+        split in half and relaunched recursively
         until the poison is cornered in a single-row launch, whose error
         is recorded in ``errors[row]``; every other row's verdict is
         bit-identical to a fault-free run (the batched executor is
@@ -418,6 +426,10 @@ class BatchValidator:
         most log2(B) distinct jit traces.  Rows already error-isolated
         at encode time (``table.errors``) launch as zeroed ok=False rows
         and keep their encode error.
+
+        The first launch of a shape is not contained: a failure to
+        trace, lower or compile it says nothing about any row, so it
+        propagates instead of turning every row into ERROR_ISOLATED.
 
         Returns ``(valid, decided, frontier, errors)``; ``errors`` rows
         are ERROR_ISOLATED -- callers must not route them to fallback.
@@ -441,11 +453,16 @@ class BatchValidator:
             # same-identity host->device transfer cache (~5% per launch)
             sub = table if full else table.take(rows)
             sub_ids = ids if full else ids[rows]
+            first_launch = (sub.batch, sub.max_nodes) not in self._seen_shapes
+            launched = False
             try:
                 if fault_hook_armed():  # skip the key tuple on the clean path
                     fault_point("launch", tuple(row_keys[i] for i in rows))
+                launched = True
                 v, d, f = self.validate_ex(sub, sub_ids)
             except Exception as exc:
+                if launched and first_launch:
+                    raise  # the shape never compiled: no row is to blame
                 if len(rows) == 1:
                     errors[rows[0]] = f"launch: {type(exc).__name__}: {exc}"
                     continue

@@ -58,6 +58,11 @@ def _eval_rows(ntype, isint, num, size, acq, pfx0, pfx1, op, f0, i0, i1, u0, u1,
     results are computed unconditionally and combined with a select chain
     on the op code -- the VPU is wide enough that computing all candidates
     costs less than divergent control flow would.
+
+    Returns an int32 0/1 matrix.  The chain selects int32 values, never
+    booleans: Mosaic rejects the i8 -> i1 truncation that a boolean select
+    or a boolean broadcast lowers to, so each candidate is widened once
+    and the caller narrows once at the store.
     """
     is_num = ntype == _T_NUM
     is_str = ntype == _T_STR
@@ -142,9 +147,10 @@ def _eval_rows(ntype, isint, num, size, acq, pfx0, pfx1, op, f0, i0, i1, u0, u1,
         (AOP.STR_EQ_PRE, r_str_eq_pre),
         (AOP.OBJ_HAS_SLOT, r_has_slot),
     ]
-    result = jnp.zeros(out_shape, jnp.bool_)
+    result = jnp.zeros(out_shape, jnp.int32)
     for code, value in candidates:
-        result = jnp.where(op == code, jnp.broadcast_to(value, out_shape), result)
+        wide = jnp.broadcast_to(value.astype(jnp.int32), out_shape)
+        result = jnp.where(op == code, wide, result)
     return result
 
 
@@ -162,14 +168,14 @@ def _assertion_kernel(
     n_acq_ref,
     n_strhash_ref,  # (BN, 8) uint32
     n_strpfx_ref,  # (BN, 2) uint32
-    # assertion columns, (BA, 1) each unless noted
+    # assertion rows, (1, BA) each unless noted
     a_op_ref,
     a_f0_ref,
     a_i0_ref,
     a_i1_ref,
     a_u0_ref,
     a_u1_ref,
-    a_hash_ref,  # (BA, 8) uint32
+    a_hash_ref,  # (8, BA) uint32, lane-major
     out_ref,  # (BN, BA) int8
 ):
     ntype = n_type_ref[...]  # (BN, 1)
@@ -177,21 +183,23 @@ def _assertion_kernel(
     num = n_num_ref[...]
     size = n_size_ref[...]
     acq = n_acq_ref[...]
-    pfx0 = n_strpfx_ref[:, 0].reshape(-1, 1)
-    pfx1 = n_strpfx_ref[:, 1].reshape(-1, 1)
+    pfx0 = n_strpfx_ref[:, 0:1]
+    pfx1 = n_strpfx_ref[:, 1:2]
 
-    op = a_op_ref[...].reshape(1, -1)  # (1, BA)
-    f0 = a_f0_ref[...].reshape(1, -1)
-    i0 = a_i0_ref[...].reshape(1, -1)
-    i1 = a_i1_ref[...].reshape(1, -1)
-    u0 = a_u0_ref[...].reshape(1, -1)
-    u1 = a_u1_ref[...].reshape(1, -1)
+    # assertion operands arrive lane-major, so no sublane->lane relayout
+    # is needed to broadcast them against the (BN, 1) node columns
+    op = a_op_ref[...]  # (1, BA)
+    f0 = a_f0_ref[...]
+    i0 = a_i0_ref[...]
+    i1 = a_i1_ref[...]
+    u0 = a_u0_ref[...]
+    u1 = a_u1_ref[...]
 
     # eight rank-2 lane-equality comparisons, statically unrolled
-    hash_eq = jnp.ones(out_ref.shape, jnp.bool_)
-    for lane in range(8):
-        nh = n_strhash_ref[:, lane].reshape(-1, 1)
-        ah = a_hash_ref[:, lane].reshape(1, -1)
+    hash_eq = n_strhash_ref[:, 0:1] == a_hash_ref[0:1, :]
+    for lane in range(1, 8):
+        nh = n_strhash_ref[:, lane : lane + 1]  # (BN, 1)
+        ah = a_hash_ref[lane : lane + 1, :]  # (1, BA)
         hash_eq = jnp.logical_and(hash_eq, nh == ah)
 
     result = _eval_rows(
@@ -222,8 +230,11 @@ def assertion_eval_pallas(
     def col2d(x):
         return x.reshape(-1, 1)
 
+    def row2d(x):
+        return x.reshape(1, -1)
+
     n_spec = pl.BlockSpec((block_n, 1), lambda i, j: (i, 0))
-    a_spec = pl.BlockSpec((block_a, 1), lambda i, j: (j, 0))
+    a_spec = pl.BlockSpec((1, block_a), lambda i, j: (0, j))
     out = pl.pallas_call(
         _assertion_kernel,
         grid=grid,
@@ -241,7 +252,7 @@ def assertion_eval_pallas(
             a_spec,
             a_spec,
             a_spec,
-            pl.BlockSpec((block_a, 8), lambda i, j: (j, 0)),
+            pl.BlockSpec((8, block_a), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_n, block_a), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, a), jnp.int8),
@@ -254,13 +265,13 @@ def assertion_eval_pallas(
         col2d(node_cols["acquired"].astype(jnp.int32)),
         node_cols["str_hash"],
         node_cols["str_prefix"],
-        col2d(asrt_cols["op"].astype(jnp.int32)),
-        col2d(asrt_cols["f0"]),
-        col2d(asrt_cols["i0"].astype(jnp.int32)),
-        col2d(asrt_cols["i1"].astype(jnp.int32)),
-        col2d(asrt_cols["u0"]),
-        col2d(asrt_cols["u1"]),
-        asrt_cols["hash"],
+        row2d(asrt_cols["op"].astype(jnp.int32)),
+        row2d(asrt_cols["f0"]),
+        row2d(asrt_cols["i0"].astype(jnp.int32)),
+        row2d(asrt_cols["i1"].astype(jnp.int32)),
+        row2d(asrt_cols["u0"]),
+        row2d(asrt_cols["u1"]),
+        jnp.transpose(asrt_cols["hash"]),
     )
     return out
 

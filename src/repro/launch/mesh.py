@@ -17,22 +17,28 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (smoke tests use (1, 1) or (1, 2) CPU meshes)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (smoke tests use (1, 1) or (1, 2) CPU meshes).
+
+    Axes are ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which ``with_sharding_constraint`` and the sharding rules'
+    gathers raise.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

@@ -23,6 +23,9 @@ def main() -> None:
     from ..configs import get_config
     from ..models import Model
     from ..serve.engine import ServeConfig, ServeEngine
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = Model(cfg)

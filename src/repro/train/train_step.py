@@ -199,8 +199,6 @@ def make_dp_compressed_step(
     """Data-parallel train step with explicit (optionally compressed)
     gradient all-reduce over every mesh axis.  Params are replicated;
     the batch is sharded over the leading axis."""
-    from jax.experimental.shard_map import shard_map
-
     axes = mesh.axis_names
     batch_spec = P(axes)
 
@@ -248,7 +246,7 @@ def make_dp_compressed_step(
         rep_tree(abstract),
         {"lr": rep, "grad_norm": rep, "loss": rep},
     )
-    mapped = shard_map(
-        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    mapped = jax.shard_map(
+        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     return jax.jit(mapped)

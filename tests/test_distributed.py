@@ -40,10 +40,11 @@ class TestShardingRules:
     def test_param_specs_resolve(self):
         code = """
         import jax
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.models import Model
         from repro.sharding import param_pspecs
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         for arch in ("granite-3-8b", "jamba-1.5-large-398b", "arctic-480b", "rwkv6-3b"):
             cfg = get_config(arch).reduced()
             aparams = jax.eval_shape(lambda k: Model(cfg).init(k), jax.random.PRNGKey(0))
@@ -67,6 +68,7 @@ class TestShardingRules:
         loss matches the single-device step."""
         code = """
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.models import Model
         from repro.sharding import shard_params
@@ -78,7 +80,7 @@ class TestShardingRules:
         tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
         ref_loss = float(model.loss(params, tokens, tokens, remat=False))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ocfg = opt.OptimizerConfig()
         step, (psh, osh, bsh), _ = make_train_step(model, ocfg, mesh, batch=8, donate=False)
         params_s = jax.device_put(params, psh)
@@ -95,15 +97,14 @@ class TestShardingRules:
     def test_compressed_psum_matches_mean(self):
         code = """
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.train.train_step import compressed_psum
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         def f(xs):
             return compressed_psum({"g": xs}, "pod")["g"]
-        out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                                out_specs=P("pod", None), check_rep=False))(x)
+        out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                                    out_specs=P("pod", None), check_vma=False))(x)
         expected = np.sum(np.asarray(x), axis=0)
         got = np.asarray(out)[0]
         err = np.abs(got - expected).max() / (np.abs(expected).max() + 1e-9)
@@ -115,6 +116,7 @@ class TestShardingRules:
     def test_dp_compressed_train_step(self):
         code = """
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.models import Model
         from repro.train import optimizer as opt
@@ -123,7 +125,7 @@ class TestShardingRules:
         model = Model(cfg)
         params = model.init(jax.random.PRNGKey(0))
         ocfg = opt.OptimizerConfig()
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
         step = make_dp_compressed_step(model, ocfg, mesh)
         opt_state = opt.init(ocfg, params)
         err = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -146,12 +148,12 @@ class TestDryRunReduced:
         code = """
         import jax, jax.numpy as jnp
         import numpy as np
-        from jax.sharding import Mesh
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.models import Model
         from repro.train import optimizer as opt
         from repro.train.train_step import make_train_step, make_decode_step
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("granite-3-8b").reduced()
         model = Model(cfg)
         ocfg = opt.OptimizerConfig()

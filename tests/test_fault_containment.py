@@ -170,6 +170,91 @@ class TestPoisonIsolation:
 
 
 # ---------------------------------------------------------------------------
+# Launch containment: what is bisected and what propagates
+# ---------------------------------------------------------------------------
+
+
+class _FailingLaunch:
+    """Stands in for a validator's jitted launch; raises on chosen batch
+    sizes and otherwise calls the real launch."""
+
+    def __init__(self, launch, fail_batches):
+        self.launch = launch
+        self.fail_batches = set(fail_batches)
+
+    def __call__(self, cols, ids):
+        if cols["node_type"].shape[0] in self.fail_batches:
+            raise RuntimeError("Mosaic refused the kernel")
+        return self.launch(cols, ids)
+
+
+class TestLaunchContainment:
+    B = 16
+
+    @pytest.fixture
+    def validator(self):
+        from repro.core.batch_executor import BatchValidator
+
+        reg = SchemaRegistry()
+        reg.register("t", SCHEMA)
+        return BatchValidator(reg.get("t").tape, use_pallas=False)
+
+    @pytest.fixture
+    def table(self):
+        from repro.data.doc_table import encode_batch
+
+        return encode_batch(_docs(self.B, seed=11), max_nodes=16)
+
+    def test_first_compile_failure_propagates(self, validator, table):
+        validator._fn = _FailingLaunch(validator._fn, {self.B})
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            validator.validate_isolated(table)
+        # the shape never compiled, so its next launch is a first one too
+        assert (self.B, table.max_nodes) not in validator.seen_shapes()
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            validator.validate_isolated(table)
+
+    def test_compiled_launch_failure_bisects_bit_identically(self, validator, table):
+        clean_valid, clean_decided, _, clean_errors = validator.validate_isolated(table)
+        assert not clean_errors
+        # the full-batch shape has compiled; a device error on it is
+        # bisected into half-batch launches that succeed
+        validator._fn = _FailingLaunch(validator._fn, {self.B})
+        valid, decided, _, errors = validator.validate_isolated(table)
+        assert not errors
+        np.testing.assert_array_equal(valid, clean_valid)
+        np.testing.assert_array_equal(decided, clean_decided)
+
+    def test_injected_launch_fault_bisects_bit_identically(self, validator, table):
+        clean_valid, clean_decided, _, _ = validator.validate_isolated(table)
+        poison = (3, 12)
+        with FaultInjector(seed=1).poison("launch", *poison) as inj:
+            valid, decided, _, errors = validator.validate_isolated(table)
+        assert inj.fired.get("launch", 0) > 0
+        assert sorted(errors) == list(poison)
+        assert all("injected" in errors[r] for r in poison)
+        keep = np.setdiff1d(np.arange(self.B), poison)
+        np.testing.assert_array_equal(valid[keep], clean_valid[keep])
+        np.testing.assert_array_equal(decided[keep], clean_decided[keep])
+        assert not decided[list(poison)].any()
+
+    def test_compile_failure_propagates_through_admission(self, registry):
+        # a batch shape no other test launches (128 rows x 24 nodes):
+        # nothing is bisected into ERROR_ISOLATED verdicts, the caller
+        # sees the failure
+        g = registry.group_of("t")
+        launch = g.validator._fn
+        g.validator._fn = _FailingLaunch(launch, {128})
+        try:
+            with pytest.raises(RuntimeError, match="Mosaic refused"):
+                registry.admit_mixed_ex(
+                    _docs(100, seed=2), ["t"] * 100, max_nodes=24
+                )
+        finally:
+            g.validator._fn = launch
+
+
+# ---------------------------------------------------------------------------
 # Admission guards + stats reconciliation
 # ---------------------------------------------------------------------------
 

@@ -87,9 +87,9 @@ class TestCheckpoint:
         """512-chip checkpoint restores onto a different mesh (here: the
         host mesh) by passing new shardings -- the node-failure path."""
         _, _, params, _, _, _ = setup
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         mgr = CheckpointManager(tmp_path, async_save=False)
         mgr.save(3, {"p": params["final_norm"]})
         shardings = {"p": jax.tree.map(
