@@ -14,9 +14,9 @@ Three questions, machine-checked across PRs via
    path (one extra detail-capturing launch over the already-encoded
    table), and whether the batched attribution agrees with the
    sequential oracle on the seeded mixed stream.
-4. **Cost attribution** (DESIGN.md §13): with a :class:`Profiler` armed
+4. **Cost attribution** (DESIGN.md §12): with a :class:`Tracer` armed
    over one end-to-end ``admit_mixed_ex`` at B=4096, the exclusive
-   phase times must explain >=90% of the measured wall window, the armed
+   span times must explain >=90% of the measured wall window, the armed
    overhead is recorded, and the disarmed admit path is compared against
    the committed HEAD baseline (the <2% disarmed-seam bar).
 
@@ -38,7 +38,7 @@ import numpy as np
 
 from repro.core.outcomes import ValidationOutcome
 from repro.data.doc_table import encode_batch
-from repro.obs import Profiler, Tracer
+from repro.obs import Tracer
 from repro.registry import SchemaRegistry
 from repro.registry.presets import GATEWAY_SCHEMAS as SCHEMAS
 
@@ -58,8 +58,8 @@ def _best_of(fn, n=5) -> float:
     return min(times)
 
 
-def _armed_admit(prof: Profiler, admit) -> None:
-    """One admit pass with the (cleared) profiler armed -- measures what
+def _armed_admit(prof: Tracer, admit) -> None:
+    """One admit pass with the (cleared) tracer armed -- measures what
     arming actually costs on top of the disarmed seams."""
     prof.clear()
     with prof:
@@ -170,8 +170,8 @@ def run(report: Dict[str, object]) -> List[str]:
         f" invalid={n_invalid}"
     )
 
-    # -- 4. cost attribution: armed profiler over one admit at B=4096 --------
-    with Profiler() as prof:
+    # -- 4. cost attribution: armed tracer over one admit at B=4096 ----------
+    with Tracer() as prof:
         t0 = time.perf_counter_ns()
         admit(False)
         window_ns = time.perf_counter_ns() - t0

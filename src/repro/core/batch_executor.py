@@ -68,7 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
-from ..obs.profile import phase as _phase
+from ..obs.compiles import KINDS as _COMPILE_KINDS, compile_events
 from ..obs.trace import span as _span, trace_point as _trace_point
 from .explain import KIND_CIRCUIT, FailureSite, resolve_site
 from .nodetypes import T_ARR as _T_ARR, T_OBJ as _T_OBJ
@@ -238,6 +238,16 @@ def _tape_consts(tape: LocationTape) -> Dict[str, jnp.ndarray]:
     }
 
 
+def _named(fn, **static):
+    """``fn`` with its static arguments bound, under ``fn``'s own name:
+    ``jax.jit`` names the program ``jit_<name>`` (a bare partial would
+    be ``jit__unknown``), which is how a profiler trace tells the launch
+    from the explain program."""
+    bound = functools.partial(fn, **static)
+    bound.__name__ = fn.__name__
+    return bound
+
+
 class BatchValidator:
     """Validates encoded token-table batches against one schema tape."""
 
@@ -270,14 +280,25 @@ class BatchValidator:
             )
             self._m_recompiles = metrics.counter(
                 "executor_recompiles_total",
-                "distinct batch shapes seen (each costs one jit trace)",
+                "backend compiles (or persistent-cache reads) inside launches",
             )
+            self._m_compiles = {
+                kind: metrics.counter(
+                    "executor_compiles_total",
+                    "JAX compile events inside launches, by kind",
+                    kind=kind,
+                )
+                for kind in _COMPILE_KINDS
+            }
             self._m_bisect_depth = metrics.histogram(
                 "executor_bisect_depth",
                 "poison-isolation bisection depth per isolated validate",
                 buckets=tuple(float(d) for d in range(13)),
             )
+        # (B, max_nodes) shapes that have launched: warm() skips them, and
+        # validate_isolated contains failures only under them
         self._seen_shapes: set = set()
+        self._compiles = compile_events()
         # compile-time window bounds (clamped: the kernels need >= 1 slot)
         self.n_window = max(1, tape.max_rows_per_loc)
         self.k_cand = max(1, tape.max_hash_run)
@@ -292,7 +313,7 @@ class BatchValidator:
         self._circuits = _circuit_static_wiring(tape)
         self._consts = _tape_consts(tape)
         self._fn = jax.jit(
-            functools.partial(
+            _named(
                 _validate_batch,
                 consts=self._consts,
                 max_depth=max_depth,
@@ -340,45 +361,41 @@ class BatchValidator:
         """
         B = table.batch
         ids = self._normalize_ids(B, schema_ids)
-        cols = {k: jnp.asarray(v) for k, v in table.columns().items()}
-        # shape churn = jit re-traces: each new (B, N) pair re-traces the
-        # launch function (the power-of-two padding upstream exists to
-        # keep this set tiny).  Tracked unconditionally: the profiler's
-        # compile-vs-execute split keys on the same first-call-under-new-
-        # shape event whether or not metrics are attached.
         shape = (B, table.max_nodes)
-        new_shape = shape not in self._seen_shapes
-        if new_shape:
-            self._seen_shapes.add(shape)
         m = self.metrics
         if m is not None:
-            if new_shape:
-                self._m_recompiles.inc()
-                _trace_point("executor.recompile", shape=shape)
             t0 = time.perf_counter()
-        # first call under a new shape pays the jit trace: attribute its
-        # whole wall time to compile, steady-state launches to execute
-        try:
-            with _phase("executor.compile" if new_shape else "executor.execute"):
-                with _span("executor.launch"):
-                    valid, in_depth, frontier = self._fn(cols, jnp.asarray(ids))
-                    valid = np.asarray(valid)  # forces device sync inside the span
-                    in_depth = np.asarray(in_depth)
-                    frontier = np.asarray(frontier)
-        except Exception:
-            # a shape whose first launch failed has not compiled: the
-            # next launch under it is a first launch again
-            if new_shape:
-                self._seen_shapes.discard(shape)
-            raise
+        before = self._compiles.snapshot()
+        # host wall from the columns' transfer to numpy results
+        with _span("executor.launch", batch=B) as sp:
+            cols = {k: jnp.asarray(v) for k, v in table.columns().items()}
+            valid, in_depth, frontier = self._fn(cols, jnp.asarray(ids))
+            valid = np.asarray(valid)  # forces device sync inside the span
+            in_depth = np.asarray(in_depth)
+            frontier = np.asarray(frontier)
+            sp.set(compiled=self._count_compiles(before, shape))
+        self._seen_shapes.add(shape)
         if m is not None:
             self._m_launches.inc()
             self._m_launch_seconds.inc(time.perf_counter() - t0)
         decided = in_depth & ~frontier & np.asarray(table.ok)
         return valid, decided, frontier & np.asarray(table.ok)
 
+    def _count_compiles(self, before: Dict[str, int], shape: Tuple[int, int]) -> bool:
+        """Bill the compile events JAX reported since ``before`` to this
+        executor; True when XLA built (or read back) an executable."""
+        fired = self._compiles.since(before)
+        if self.metrics is not None:
+            for kind, n in fired.items():
+                if n:
+                    self._m_compiles[kind].inc(n)
+            self._m_recompiles.inc(fired["compile"])
+        if fired["compile"]:
+            _trace_point("executor.recompile", shape=shape, **fired)
+        return fired["compile"] > 0
+
     def seen_shapes(self) -> set:
-        """Snapshot of the (B, max_nodes) launch shapes already traced."""
+        """Snapshot of the (B, max_nodes) shapes that have launched."""
         return set(self._seen_shapes)
 
     def warm(self, table, schema_ids=None) -> bool:
@@ -516,7 +533,7 @@ class BatchValidator:
             raise ValueError(f"{len(docs)} docs for batch of {B}")
         if self._explain_fn is None:
             self._explain_fn = jax.jit(
-                functools.partial(
+                _named(
                     _explain_batch,
                     consts=self._consts,
                     max_depth=self.max_depth,
@@ -530,9 +547,11 @@ class BatchValidator:
                     n_circuits=self.n_circuits,
                 )
             )
-        cols = {k: jnp.asarray(v) for k, v in table.columns().items()}
-        with _phase("executor.explain"), _span("executor.explain", batch=B):
+        before = self._compiles.snapshot()
+        with _span("executor.explain", batch=B) as sp:
+            cols = {k: jnp.asarray(v) for k, v in table.columns().items()}
             out = self._explain_fn(cols, jnp.asarray(ids))
+            sp.set(compiled=self._count_compiles(before, (B, table.max_nodes)))
         doc_key, bad_row, bad_loc, parent_loc, missing, root_fail, root_anchor = (
             np.asarray(x) for x in out
         )

@@ -19,7 +19,7 @@ conformance oracle.  This deviation is recorded in DESIGN.md §7.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -27,7 +27,6 @@ from ..core.doc_model import HashedObject
 from ..core.hashing import SHORT_LIMIT, hash_lanes, shash_bytes
 from ..core.nodetypes import TYPE_CODES
 from ..core.outcomes import fault_point
-from ..obs.profile import phase as _phase, profiler_armed as _profiler_armed
 
 __all__ = ["TokenTable", "encode_document", "encode_batch", "key_lanes", "TYPE_CODES"]
 
@@ -131,15 +130,11 @@ def encode_document(
     doc: Any,
     max_nodes: int = 256,
     max_depth: int = 16,
-    hash_fn: Callable[[str], np.ndarray] = key_lanes,
 ) -> Optional[Dict[str, np.ndarray]]:
     """Encode one parsed JSON value into single-document columns (N,).
 
     Returns None when the document exceeds the node or depth budget
-    (callers fall back to the sequential executor).  ``hash_fn``
-    computes the 8-lane key/string hash; an armed profiler swaps in a
-    timed wrapper (``encode_batch``) so the walk/hash split is
-    attributable without taxing the disarmed path.
+    (callers fall back to the sequential executor).
     """
     cols = {
         "node_type": np.zeros(max_nodes, np.int8),
@@ -168,7 +163,7 @@ def encode_document(
         cols["depth"][i] = depth
         cols["idx_in_parent"][i] = idx
         if key is not None:
-            cols["key_hash"][i] = hash_fn(key)
+            cols["key_hash"][i] = key_lanes(key)
         if value is None:
             cols["node_type"][i] = TYPE_CODES["null"]
         elif isinstance(value, bool):
@@ -184,7 +179,7 @@ def encode_document(
             data = value.encode("utf-8")
             cols["node_type"][i] = TYPE_CODES["string"]
             cols["size"][i] = len(value)  # code points, matching len(str)
-            cols["str_hash"][i] = hash_fn(value)
+            cols["str_hash"][i] = key_lanes(value)
             p0, p1 = _str_prefix8(data)
             cols["str_prefix"][i] = (p0, p1)
             cols["str_last"][i] = data[-1] if data else 0
@@ -232,29 +227,19 @@ def encode_batch(
     errors: Dict[int, str] = {}
     template = encode_document(None, max_nodes)
     zero_cols = None
-    # armed profiler: walk vs hash attribution (encode.hash nests inside
-    # encode.walk, so exclusive times split the encode tax); disarmed,
-    # hash_fn stays the raw key_lanes and the per-key path pays nothing
-    if _profiler_armed():
-        def hash_fn(s: str) -> np.ndarray:
-            with _phase("encode.hash"):
-                return key_lanes(s)
-    else:
-        hash_fn = key_lanes
     for b, doc in enumerate(docs):
-        with _phase("encode.walk"):
-            if isolate:
-                try:
-                    fault_point("encode", keys[b] if keys is not None else b)
-                    cols = encode_document(doc, max_nodes, max_depth, hash_fn)
-                except RecursionError:
-                    errors[b] = "encode recursion limit exceeded"
-                    cols = None
-                except Exception as exc:  # isolated per-document fault
-                    errors[b] = f"{type(exc).__name__}: {exc}"
-                    cols = None
-            else:
-                cols = encode_document(doc, max_nodes, max_depth, hash_fn)
+        if isolate:
+            try:
+                fault_point("encode", keys[b] if keys is not None else b)
+                cols = encode_document(doc, max_nodes, max_depth)
+            except RecursionError:
+                errors[b] = "encode recursion limit exceeded"
+                cols = None
+            except Exception as exc:  # isolated per-document fault
+                errors[b] = f"{type(exc).__name__}: {exc}"
+                cols = None
+        else:
+            cols = encode_document(doc, max_nodes, max_depth)
         if cols is None:
             ok[b] = False  # budget overflow (fallback) or isolated error row
             if zero_cols is None:
@@ -268,6 +253,5 @@ def encode_batch(
         n_nodes[b] = cols.pop("n_nodes")
         for k, v in cols.items():
             stacked.setdefault(k, []).append(v)
-    with _phase("encode.pack"):
-        arrays = {k: np.stack(v) for k, v in stacked.items()}
+    arrays = {k: np.stack(v) for k, v in stacked.items()}
     return TokenTable(n_nodes=n_nodes, ok=ok, errors=errors, **arrays)

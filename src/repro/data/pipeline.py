@@ -22,7 +22,7 @@ import numpy as np
 
 from ..core import Validator
 from ..core.outcomes import Verdict
-from ..obs.profile import phase as _phase
+from ..obs.trace import span as _span
 from ..obs.stats import RegistryBackedStats
 from ..registry import SchemaRegistry
 from . import tokenizer
@@ -178,10 +178,9 @@ class AdmissionController:
         if endpoints is None:
             endpoints = [self.endpoint] * len(records)
         self.stats.seen += len(records)
-        # top-level attribution root: admit.* / encode.* / executor.* /
-        # fallback.* phases nest under it, so its exclusive time is the
-        # controller's own bookkeeping
-        with _phase("pipeline.admit"):
+        # root span: admit.* and executor.* spans nest under it, so its
+        # exclusive time is the controller's own bookkeeping
+        with _span("pipeline.admit", batch=len(records)):
             verdicts, counts = self.registry.admit_mixed_ex(
                 records,
                 endpoints,

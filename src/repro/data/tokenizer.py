@@ -11,8 +11,6 @@ from typing import Iterable, List
 
 import numpy as np
 
-from ..obs.profile import phase as _phase
-
 BOS = 256
 EOS = 257
 PAD = 258
@@ -35,13 +33,12 @@ def decode(ids: Iterable[int]) -> str:
 
 def pack(texts: Iterable[str], seq_len: int) -> np.ndarray:
     """Pack encoded texts into (N, seq_len) rows (train-time packing)."""
-    with _phase("tokenize.pack"):
-        stream: List[int] = []
-        for t in texts:
-            stream.extend(encode(t))
-        n = max(1, len(stream) // seq_len)
-        stream = stream[: n * seq_len]
-        if not stream:
-            stream = [PAD] * seq_len
-            n = 1
-        return np.asarray(stream, np.int32).reshape(n, seq_len)
+    stream: List[int] = []
+    for t in texts:
+        stream.extend(encode(t))
+    n = max(1, len(stream) // seq_len)
+    stream = stream[: n * seq_len]
+    if not stream:
+        stream = [PAD] * seq_len
+        n = 1
+    return np.asarray(stream, np.int32).reshape(n, seq_len)

@@ -1,11 +1,11 @@
-"""Serving observability: tracing, metrics, profiling, events, SLOs.
+"""Serving observability: tracing, metrics, events, SLOs.
 
-Spans + metric registry are DESIGN.md §12; the cost-attribution
-profiler, sampled event log, and SLO/burn-rate tracking are §13.
+Spans, the compile counter and the metric registry are DESIGN.md §12;
+the sampled event log and SLO tracking are §13.
 
 Zero-dependency by design -- the serve stack imports this package
 unconditionally, so it must cost nothing when disarmed: ``span()``/
-``trace_point()``/``phase()`` pay one module-global ``None`` check (the
+``trace_point()`` pay one module-global ``None`` check (the
 ``fault_point`` contract), and registry-backed counters are plain
 attribute adds.
 """
@@ -18,17 +18,12 @@ from .metrics import (
     MetricRegistry,
     DEFAULT_LATENCY_BUCKETS,
 )
-from .profile import (
-    PhaseStat,
-    Profiler,
-    phase,
-    profiler_armed,
-    set_profiler,
-)
 from .slo import SLObjective, SLOTracker, slo_status
 from .stats import RegistryBackedStats
 from .trace import (
+    SPANS,
     Span,
+    SpanStat,
     Tracer,
     set_tracer,
     span,
@@ -44,15 +39,12 @@ __all__ = [
     "MetricRegistry",
     "DEFAULT_LATENCY_BUCKETS",
     "EventLog",
-    "PhaseStat",
-    "Profiler",
-    "phase",
-    "profiler_armed",
-    "set_profiler",
     "SLObjective",
     "SLOTracker",
     "slo_status",
+    "SPANS",
     "Span",
+    "SpanStat",
     "Tracer",
     "set_tracer",
     "span",

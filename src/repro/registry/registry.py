@@ -60,7 +60,6 @@ from ..core.outcomes import (
 from ..core.explain import FailureSite, keyword_of
 from ..core.tape import DEFAULT_UNROLL_DEPTH, LocationTape, try_build_tape
 from ..obs.metrics import MetricRegistry
-from ..obs.profile import phase as _phase
 from ..obs.trace import span as _span
 from .linker import (
     LinkedTape,
@@ -349,8 +348,7 @@ class SchemaRegistry:
         report: Optional[AnalysisReport] = None
         lowered = schema
         if self.analysis:
-            with _phase("analyze"):
-                report = analyze_schema(schema, verify=(verify != "off"))
+            report = analyze_schema(schema, verify=(verify != "off"))
             lowered = report.normalized
         # -- subsumption proof vs the serving version -------------------------
         verdict = ""
@@ -999,7 +997,7 @@ class SchemaRegistry:
             raise ValueError(f"{len(row_keys)} keys for {len(docs)} docs")
         verdicts: List[Optional[Verdict]] = [None] * len(docs)
         counts = AdmitCounts()
-        with _phase("admit.guard"), _span("registry.guard", batch=len(docs)):
+        with _span("admit.guard", batch=len(docs)):
             for i, doc in enumerate(docs):
                 why = resource_guard(doc, self.guard)
                 if why:
@@ -1028,24 +1026,24 @@ class SchemaRegistry:
                 max_nodes=max_nodes,
                 explain=explain,
             )
-        with _phase("admit.verdicts"):
-            for i in range(len(docs)):
-                if verdicts[i] is None:
-                    v = self._bounded_fallback(
-                        endpoints[i], docs[i], row_keys[i], explain=explain
-                    )
-                    verdicts[i] = v
-                    if v.outcome in (
-                        ValidationOutcome.ADMITTED,
-                        ValidationOutcome.INVALID,
-                    ):
-                        counts.fallback_validated += 1
-                    elif v.outcome is ValidationOutcome.TIMED_OUT:
-                        counts.timed_out += 1
-                    elif v.outcome is ValidationOutcome.UNDECIDED_FALLBACK:
-                        counts.breaker_open += 1
-                    else:
-                        counts.error_isolated += 1
+        rest = [i for i in range(len(docs)) if verdicts[i] is None]
+        with _span("admit.fallback", rows=len(rest)):
+            for i in rest:
+                v = self._bounded_fallback(
+                    endpoints[i], docs[i], row_keys[i], explain=explain
+                )
+                verdicts[i] = v
+                if v.outcome in (
+                    ValidationOutcome.ADMITTED,
+                    ValidationOutcome.INVALID,
+                ):
+                    counts.fallback_validated += 1
+                elif v.outcome is ValidationOutcome.TIMED_OUT:
+                    counts.timed_out += 1
+                elif v.outcome is ValidationOutcome.UNDECIDED_FALLBACK:
+                    counts.breaker_open += 1
+                else:
+                    counts.error_isolated += 1
         return verdicts, counts  # type: ignore[return-value]
 
     def _admit_group(
@@ -1080,9 +1078,7 @@ class SchemaRegistry:
         fast_keys = [row_keys[i] for i in rows] + [
             ("__pad__", j) for j in range(pad)
         ]
-        with _phase("admit.encode"), _span(
-            "registry.encode", batch=bucket, group=g.label
-        ):
+        with _span("admit.encode", batch=bucket, group=g.label):
             table = encode_batch(
                 [docs[i] for i in rows] + [None] * pad,
                 max_nodes=max_nodes,
@@ -1094,8 +1090,8 @@ class SchemaRegistry:
             np.int32,
         )
         # admit.launch's exclusive time is the bisect/bookkeeping
-        # overhead around the executor.compile/execute children
-        with _phase("admit.launch"):
+        # overhead around its executor.launch children
+        with _span("admit.launch", group=g.label):
             valid, decided, frontier, errors = g.validator.validate_isolated(
                 table, ids, keys=fast_keys
             )
@@ -1108,7 +1104,7 @@ class SchemaRegistry:
             # argmax over per-row failures (core/explain.py); rows we
             # don't attribute below are simply ignored
             try:
-                with _phase("admit.explain"):
+                with _span("admit.explain", group=g.label):
                     sites = g.validator.explain_batch(
                         table,
                         ids,
@@ -1116,7 +1112,7 @@ class SchemaRegistry:
                     )
             except Exception:
                 sites = []  # attribution is best-effort diagnostics
-        with _phase("admit.verdicts"):
+        with _span("admit.verdicts", group=g.label):
             for j, i in enumerate(rows):
                 if j in errors:
                     verdicts[i] = Verdict(
@@ -1210,12 +1206,9 @@ class SchemaRegistry:
                 deadline_s=self.fallback_deadline_s,
                 clock=self.clock,
             )
-            with _phase("fallback.sequential"), _span(
-                "registry.fallback", endpoint=endpoint
-            ):
-                ok = self.get(endpoint).validator.is_valid_bounded(
-                    doc, budget=budget
-                )
+            ok = self.get(endpoint).validator.is_valid_bounded(
+                doc, budget=budget
+            )
         except (ValidationTimeout, DocumentDepthError) as exc:
             breaker.record_timeout()
             self._breaker_gauge(endpoint, breaker)

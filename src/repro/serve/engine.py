@@ -40,7 +40,6 @@ from ..models.config import ArchConfig
 from ..models.model import Model
 from ..obs.events import EventLog
 from ..obs.metrics import DEFAULT_LATENCY_BUCKETS, Histogram, MetricRegistry
-from ..obs.profile import phase as _phase
 from ..obs.slo import SLObjective, slo_status
 from ..obs.stats import RegistryBackedStats
 from ..obs.trace import span as _span
@@ -468,7 +467,8 @@ class ServeEngine:
         self.stats.received += 1
         serial = self.stats.received
         t0 = time.perf_counter()
-        request, err = self._parse(request_json, endpoint)
+        with _span("serve.parse"):
+            request, err = self._parse(request_json, endpoint)
         if stages is not None:
             stages["parse_s"] = time.perf_counter() - t0
         if err:
@@ -543,7 +543,7 @@ class ServeEngine:
             out: List[Optional[SubmitResult]] = [None] * len(requests)
             parsed: List[Tuple[int, str, Any, int]] = []
             guard_rejected: List[Tuple[int, str, float]] = []
-            with _phase("serve.parse"):
+            with _span("serve.parse"):
                 for i, (endpoint, request_json) in enumerate(requests):
                     self.stats.received += 1
                     serial = self.stats.received
@@ -568,9 +568,7 @@ class ServeEngine:
                 endpoints = [e for _, e, _, _ in parsed]
                 keys = [("batch", s) for _, _, _, s in parsed]
                 t0 = time.perf_counter()
-                with _phase("serve.validate"), _span(
-                    "serve.validate", batch=len(parsed)
-                ):
+                with _span("serve.validate", batch=len(parsed)):
                     verdicts, counts = self.registry.admit_mixed_ex(
                         docs,
                         endpoints,
@@ -594,7 +592,7 @@ class ServeEngine:
                 for endpoint, n in ep_rows.items():
                     self._latency(endpoint).observe_many(per_row, n)
                 ev = self.events
-                with _phase("serve.dispatch"):
+                with _span("serve.dispatch"):
                     for (i, endpoint, request, serial), verdict in zip(
                         parsed, verdicts
                     ):
@@ -649,8 +647,7 @@ class ServeEngine:
             self.stats.record_outcome(ValidationOutcome.REJECTED_GUARD)
             return None, f"payload {len(request_json)} bytes > guard cap {limit}"
         try:
-            with _span("serve.parse", bytes=len(request_json)):
-                request = json.loads(request_json)
+            request = json.loads(request_json)
         except json.JSONDecodeError as exc:
             self.stats.rejected += 1
             self.stats.count(endpoint, "rejected")

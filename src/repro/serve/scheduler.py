@@ -22,7 +22,7 @@ path into a stream runtime (DESIGN.md §14):
   bounded oracle).  Small or cold bursts go sequential, hot bursts ride
   the group's linked tape.  Priors are seeded from committed ``BENCH_*``
   measurements and updated online with per-(lane, bucket) EMAs; sampled
-  drains arm the §13 phase profiler so the update reads *attributed*
+  drains arm the tracer (§12) so the update reads *attributed*
   encode+launch (or fallback) time rather than drain bookkeeping.
 
 Both routes produce verdicts through the registry's containment ladder
@@ -50,8 +50,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 
 from ..core.outcomes import ValidationOutcome
 from ..obs.metrics import DEFAULT_LATENCY_BUCKETS
-from ..obs.profile import Profiler, profiler_armed, set_profiler
-from ..obs.trace import span as _span
+from ..obs.trace import Tracer, set_tracer, span as _span, tracer_armed
 from .engine import SubmitResult
 
 __all__ = [
@@ -80,7 +79,7 @@ class SchedulerConfig:
     launch_us_per_doc: float = 100.0  # marginal batched cost per rider
     seq_us_per_doc: float = 25.0  # bounded sequential oracle per doc
     ema_alpha: float = 0.25  # online update weight
-    profile_every: int = 16  # arm the §13 profiler every Nth drain (0=off)
+    profile_every: int = 16  # arm the tracer every Nth drain (0=off)
     # "auto" = seed priors from results/BENCH_registry.json when present;
     # a path seeds from that file; None/"" keeps the config priors
     bench_priors: Optional[str] = "auto"
@@ -432,18 +431,18 @@ class StreamScheduler:
         docs = [it.request for it in items]
         endpoints = [it.ticket.endpoint for it in items]
         keys = [("stream", it.ticket.serial) for it in items]
-        # sampled §13 attribution: every Nth drain arms the phase
-        # profiler (unless someone else is measuring) so the cost-model
-        # update reads attributed encode+launch / fallback time
-        prof: Optional[Profiler] = None
+        # sampled §13 attribution: every Nth drain arms the tracer
+        # (unless someone else is measuring) so the cost-model update
+        # reads attributed encode+launch / fallback time
+        prof: Optional[Tracer] = None
         sample = (
             self.cfg.profile_every > 0
             and self.stats.drains % self.cfg.profile_every == 0
-            and not profiler_armed()
+            and not tracer_armed()
         )
         if sample:
-            prof = Profiler()
-            set_profiler(prof)
+            prof = Tracer(capacity=64)
+            set_tracer(prof)
         t0 = time.perf_counter()
         try:
             with _span("serve.drain", lane=lane, route=route, batch=n):
@@ -462,20 +461,21 @@ class StreamScheduler:
                     eng.stats.unroll_overflow += counts.unroll_overflow
                 else:
                     verdicts = []
-                    for doc, endpoint, key in zip(docs, endpoints, keys):
-                        v = eng.registry.validate_one(
-                            endpoint, doc, key=key, explain=self.cfg.explain
-                        )
-                        if v.outcome in (
-                            ValidationOutcome.ADMITTED,
-                            ValidationOutcome.INVALID,
-                        ):
-                            eng.stats.fallback_validated += 1
-                        verdicts.append(v)
+                    with _span("admit.fallback", rows=n):
+                        for doc, endpoint, key in zip(docs, endpoints, keys):
+                            v = eng.registry.validate_one(
+                                endpoint, doc, key=key, explain=self.cfg.explain
+                            )
+                            if v.outcome in (
+                                ValidationOutcome.ADMITTED,
+                                ValidationOutcome.INVALID,
+                            ):
+                                eng.stats.fallback_validated += 1
+                            verdicts.append(v)
         finally:
             wall = time.perf_counter() - t0
             if sample:
-                set_profiler(None)
+                set_tracer(None)
         eng.stats.validation_seconds += wall
         self._observe_cost(lane, route, n, wall, prof)
         self.stats.drains += 1
@@ -511,15 +511,15 @@ class StreamScheduler:
         route: str,
         n: int,
         wall_s: float,
-        prof: Optional[Profiler],
+        prof: Optional[Tracer],
     ) -> None:
-        """Online cost-model update; attributed phase time when sampled.
+        """Online cost-model update; attributed span time when sampled.
 
-        On sampled drains the observation is the profiler's
+        On sampled drains the observation is the tracer's
         encode+launch(+explain) total for batched routes, or the
-        ``fallback.sequential`` total for sequential routes -- the part
+        ``admit.fallback`` total for sequential routes -- the part
         of the drain a *bigger batch would amortize* -- falling back to
-        raw wall when the phases did not fire (e.g. everything
+        raw wall when the spans did not fire (e.g. everything
         guard-rejected).
         """
         us = wall_s * 1e6
@@ -528,7 +528,7 @@ class StreamScheduler:
             names = (
                 ("admit.encode", "admit.launch", "admit.explain")
                 if route == "batched"
-                else ("fallback.sequential",)
+                else ("admit.fallback",)
             )
             attributed = sum(
                 stats[p].total_ns for p in names if p in stats

@@ -167,8 +167,8 @@ class TestTracer:
             reg.admit_mixed_ex([{"a": 1}, {"a": -1}], ["ep", "ep"])
         names = {s.name for s in tr.recent()}
         assert "registry.relink" in names
-        assert "registry.guard" in names
-        assert "registry.encode" in names
+        assert "admit.guard" in names
+        assert "admit.encode" in names
         assert "executor.launch" in names
 
 

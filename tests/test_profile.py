@@ -1,10 +1,10 @@
-"""Cost-attribution profiler, event log, and SLO tracking (DESIGN.md §13).
+"""Cost attribution, event log, and SLO tracking (DESIGN.md §12-13).
 
-Three layers under test: the :mod:`repro.obs.profile` seam contract
+Three layers under test: the span aggregate of :mod:`repro.obs.trace`
 (disarmed is one None check, armed attribution is nesting-aware and
 double-count-free), the sampled :class:`EventLog` ring, and the
 SLO/burn-rate math over the serving latency histograms -- plus the
-profiler-armed smoke test over the real admission path that CI runs in
+tracer-armed smoke test over the real admission path that CI runs in
 tier-1.
 """
 
@@ -16,11 +16,11 @@ import pytest
 
 from repro.obs.events import EventLog
 from repro.obs.metrics import Histogram
-from repro.obs.profile import (
-    Profiler,
-    phase,
-    profiler_armed,
-    set_profiler,
+from repro.obs.trace import (
+    Tracer,
+    set_tracer,
+    span,
+    tracer_armed,
 )
 from repro.obs.slo import SLObjective, SLOTracker, good_count, slo_status
 from repro.registry import SchemaRegistry
@@ -34,34 +34,34 @@ SCHEMA = {
 
 
 # ---------------------------------------------------------------------------
-# profiler
+# the tracer's aggregate
 # ---------------------------------------------------------------------------
 
 
 class TestProfiler:
     def test_disarmed_is_noop(self):
-        assert not profiler_armed()
-        with phase("anything"):
+        assert not tracer_armed()
+        with span("anything"):
             pass  # must not raise, must not record
         # the disarmed seam returns one shared object (no allocation)
-        assert phase("a") is phase("b")
+        assert span("a") is span("b")
 
     def test_phases_accumulate(self):
-        with Profiler() as prof:
-            assert profiler_armed()
+        with Tracer() as prof:
+            assert tracer_armed()
             for _ in range(3):
-                with phase("work"):
+                with span("work"):
                     pass
-        assert not profiler_armed()  # disarmed on exit
+        assert not tracer_armed()  # disarmed on exit
         stats = prof.stats()
         assert stats["work"].calls == 3
         assert stats["work"].total_ns >= stats["work"].self_ns >= 0
 
     def test_nested_phases_attribute_exclusive_time(self):
-        with Profiler() as prof:
-            with phase("outer"):
+        with Tracer() as prof:
+            with span("outer"):
                 time.sleep(0.002)
-                with phase("inner"):
+                with span("inner"):
                     time.sleep(0.004)
         outer, inner = prof.stats()["outer"], prof.stats()["inner"]
         # inclusive: outer contains inner; exclusive: outer excludes it
@@ -72,11 +72,11 @@ class TestProfiler:
         assert prof.attributed_ns() <= outer.total_ns
 
     def test_coverage_and_report(self):
-        with Profiler() as prof:
+        with Tracer() as prof:
             t0 = time.perf_counter_ns()
-            with phase("a"):
+            with span("a"):
                 time.sleep(0.002)
-            with phase("b"):
+            with span("b"):
                 time.sleep(0.001)
             window = time.perf_counter_ns() - t0
         cov = prof.coverage(window)
@@ -89,38 +89,38 @@ class TestProfiler:
         assert prof.coverage(0) == 0.0
         prof.clear()
         assert prof.stats() == {} and prof.attributed_ns() == 0
+        assert prof.recent() == []
 
     def test_nested_arming_restores_previous(self):
-        outer = Profiler()
-        prev = set_profiler(outer)
+        outer = Tracer()
+        prev = set_tracer(outer)
         try:
-            with Profiler() as inner:
-                with phase("x"):
+            with Tracer() as inner:
+                with span("x"):
                     pass
             assert "x" in inner.stats() and "x" not in outer.stats()
-            with phase("y"):
+            with span("y"):
                 pass
             assert "y" in outer.stats()  # restored
         finally:
-            set_profiler(prev)
+            set_tracer(prev)
 
     def test_admission_path_attribution_smoke(self):
-        """The tier-1 armed smoke: a profiler over a real mixed admission
-        must see the taxonomy phases and explain most of the window."""
+        """The tier-1 armed smoke: a tracer over a real mixed admission
+        must see the declared stage spans and explain most of the window."""
         reg = SchemaRegistry(use_pallas=False)
         reg.register("ep", SCHEMA)
         docs = [{"a": i} for i in range(24)] + [{"a": -1}, {}, {"a": "x"}]
         eps = ["ep"] * len(docs)
         reg.admit_mixed_ex(docs, eps)  # warm the jit outside the window
-        with Profiler() as prof:
+        with Tracer() as prof:
             t0 = time.perf_counter_ns()
             verdicts, _ = reg.admit_mixed_ex(docs, eps)
             window = time.perf_counter_ns() - t0
         assert len(verdicts) == len(docs)
         names = set(prof.stats())
         assert {"admit.guard", "admit.encode", "admit.launch",
-                "admit.verdicts", "encode.walk", "encode.hash",
-                "encode.pack", "executor.execute"} <= names
+                "admit.verdicts", "admit.fallback", "executor.launch"} <= names
         # warm small-batch coverage is noisier than the B=4096 bench bar
         assert prof.coverage(window) > 0.5
 
