@@ -13,6 +13,16 @@ Notes on specialization:
 * scalar const/enum tests split by type at compile time -- enum membership
   is one frozenset probe, no json_equal walk;
 * property matching uses dicts keyed by the semi-perfect hash, built once.
+
+Metering (DESIGN.md §11): one closure set serves both the unbounded hot
+path and the bounded fallback.  Every group closure reads the ``budget``
+slot of the validator's :class:`~repro.core.executor.EvalContext`.  Left
+``None``, a group pays that one check; set to a
+:class:`~repro.core.outcomes.ValidationBudget`, a group meters exactly as
+``executor._eval_group`` does -- ``enter_group``, one ``tick`` before
+each instruction, ``exit_group`` -- and engine regexes pass the budget's
+``regex_gate`` first, so step counts, depth bounds and refusals match the
+interpreter's.
 """
 
 from __future__ import annotations
@@ -89,28 +99,69 @@ def _enum_check(values: Tuple[Any, ...]) -> Check:
 
 
 class _Codegen:
-    def __init__(self, compiled: CompiledSchema):
+    def __init__(self, compiled: CompiledSchema, meter: Any):
         self.compiled = compiled
         self.labels: Dict[int, Check] = {}
+        self.meter = meter
 
     # -- groups ---------------------------------------------------------------
 
     def group(self, instructions: Instructions) -> Check:
-        fns = [self.one(i) for i in instructions]
-        if not fns:
-            return lambda v: True
+        """AND over a group, fail-fast; metered while ``meter.budget`` is
+        set.  The one- and two-instruction forms specialise only the
+        unmetered path: the metered one is the interpreter's loop."""
+        fns = tuple(self.one(i) for i in instructions)
+        meter = self.meter
         if len(fns) == 1:
-            return fns[0]
+            (f0,) = fns
+
+            def check1(v):
+                budget = meter.budget
+                if budget is None:
+                    return f0(v)
+                budget.enter_group()
+                try:
+                    budget.tick()
+                    return f0(v)
+                finally:
+                    budget.exit_group()
+
+            return check1
         if len(fns) == 2:
             f0, f1 = fns
-            return lambda v: f0(v) and f1(v)
-        fns_t = tuple(fns)
+
+            def check2(v):
+                budget = meter.budget
+                if budget is None:
+                    return f0(v) and f1(v)
+                budget.enter_group()
+                try:
+                    budget.tick()
+                    if not f0(v):
+                        return False
+                    budget.tick()
+                    return f1(v)
+                finally:
+                    budget.exit_group()
+
+            return check2
 
         def check(v):
-            for f in fns_t:
-                if not f(v):
-                    return False
-            return True
+            budget = meter.budget
+            if budget is None:
+                for f in fns:
+                    if not f(v):
+                        return False
+                return True
+            budget.enter_group()
+            try:
+                for f in fns:
+                    budget.tick()
+                    if not f(v):
+                        return False
+                return True
+            finally:
+                budget.exit_group()
 
         return check
 
@@ -253,7 +304,18 @@ class _Codegen:
             from .regex_opt import _engine
 
             rx = _engine(plan.source)
-            return lambda v: type(v) is not str or rx.search(v) is not None
+            meter = self.meter
+
+            def engine_regex(v):
+                if type(v) is not str:
+                    return True
+                budget = meter.budget
+                if budget is not None:
+                    # not preemptible mid-match: gate it up front
+                    budget.regex_gate(plan, len(v))
+                return rx.search(v) is not None
+
+            return engine_regex
         if op is OpCode.STRING_SIZE_GREATER:
             b = inst.bound
             return lambda v: type(v) is not str or len(v) >= b
@@ -622,9 +684,12 @@ class _Codegen:
         raise AssertionError(f"codegen: unhandled opcode {op!r}")
 
 
-def compile_to_callable(compiled: CompiledSchema) -> Check:
-    """Compile a CompiledSchema into a single specialised closure."""
-    gen = _Codegen(compiled)
+def compile_to_callable(compiled: CompiledSchema, meter: Any) -> Check:
+    """Compile a CompiledSchema into a single specialised closure.
+
+    ``meter`` is the object whose ``budget`` attribute the closures read
+    (the validator's EvalContext): they meter themselves while it is set."""
+    gen = _Codegen(compiled, meter)
     # labels referenced by jumps may be registered during group compilation;
     # compile label bodies first so forward jumps resolve
     for label, group in compiled.labels.items():

@@ -64,7 +64,8 @@ class EvalContext:
         # the hot path; a list during Validator.explain()
         self.trace = None
         # fallback deadline/step budget (DESIGN.md §11): None on the hot
-        # path; a ValidationBudget during Validator.is_valid_bounded()
+        # path; a ValidationBudget during Validator.is_valid_bounded(),
+        # read by the interpreter and the codegen closures alike
         self.budget = None
 
 
@@ -120,7 +121,7 @@ class Validator:
         if engine == "codegen":
             from .codegen import compile_to_callable
 
-            self._fn = compile_to_callable(compiled)
+            self._fn = compile_to_callable(compiled, self.ctx)
 
     # -- public API ----------------------------------------------------------
 
@@ -157,8 +158,10 @@ class Validator:
         :class:`~repro.core.outcomes.DocumentDepthError` when parsing
         itself over-recurses -- depth bombs and pathological ``pattern``
         backtracking become structured rejects instead of a stalled
-        engine.  Always runs the instruction interpreter: the codegen
-        closures are the unmetered hot path, by design.
+        engine.  Runs the validator's own engine under one meter: the
+        codegen closures (the serving fallback's engine) meter exactly as
+        the interpreter does -- the same steps, deadline cadence, depth
+        bound and regex refusals (DESIGN.md §11).
         """
         budget.check_deadline()
         try:
@@ -169,6 +172,8 @@ class Validator:
             ) from None
         self.ctx.budget = budget
         try:
+            if self._fn is not None:
+                return self._fn(doc)
             return _eval_group(self.compiled.instructions, doc, self.ctx)
         except RecursionError:
             raise ValidationTimeout(

@@ -263,6 +263,9 @@ class SchemaRegistry:
             "registry_relinks_total", "linked-tape re-cuts (membership changes)"
         )
         self._breakers: Dict[str, CircuitBreaker] = {}
+        # engine -> rows handed to a fallback validator, not yet published
+        # to registry_fallback_rows_total
+        self._fallback_rows: Dict[str, int] = {}
         self._swap_failures: Dict[str, str] = {}
         # endpoint -> subsumption verdict of its most recent hot-swap
         # (equivalent / widened / narrowed / incomparable / unknown)
@@ -1044,6 +1047,7 @@ class SchemaRegistry:
                     counts.breaker_open += 1
                 else:
                     counts.error_isolated += 1
+        self._flush_fallback_rows()
         return verdicts, counts  # type: ignore[return-value]
 
     def _admit_group(
@@ -1188,6 +1192,17 @@ class SchemaRegistry:
         path, _instr = trace[0]  # innermost failure first
         return FailureSite(path, keyword_of(path))
 
+    def _flush_fallback_rows(self) -> None:
+        """Publish the rows the fallback handed to a validator since the
+        last flush, per engine: once per loop, never per row."""
+        for engine, n in self._fallback_rows.items():
+            self.metrics.counter(
+                "registry_fallback_rows_total",
+                "rows the bounded sequential fallback evaluated, per engine",
+                engine=engine,
+            ).inc(n)
+        self._fallback_rows.clear()
+
     def _bounded_fallback(
         self, endpoint: str, doc: Any, key: Any, *, explain: bool = False
     ) -> Verdict:
@@ -1206,9 +1221,10 @@ class SchemaRegistry:
                 deadline_s=self.fallback_deadline_s,
                 clock=self.clock,
             )
-            ok = self.get(endpoint).validator.is_valid_bounded(
-                doc, budget=budget
-            )
+            validator = self.get(endpoint).validator
+            tally = self._fallback_rows
+            tally[validator.engine] = tally.get(validator.engine, 0) + 1
+            ok = validator.is_valid_bounded(doc, budget=budget)
         except (ValidationTimeout, DocumentDepthError) as exc:
             breaker.record_timeout()
             self._breaker_gauge(endpoint, breaker)
@@ -1250,6 +1266,8 @@ class SchemaRegistry:
         why = resource_guard(doc, self.guard)
         if why:
             return Verdict(ValidationOutcome.REJECTED_GUARD, False, why)
-        return self._bounded_fallback(
+        verdict = self._bounded_fallback(
             endpoint, doc, key if key is not None else endpoint, explain=explain
         )
+        self._flush_fallback_rows()
+        return verdict

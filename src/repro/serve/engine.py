@@ -239,7 +239,8 @@ class ServeEngine:
         self.slo_default = slo if slo is not None else DEFAULT_SLO
         self._slo: Dict[str, SLObjective] = {}
         # compiled ONCE per endpoint; validated per request -- the paper's
-        # AOT bet (codegen engine on the request-critical path).  The
+        # AOT bet (codegen engine on the request-critical path: the
+        # bounded sequential fallback runs the metered closures).  The
         # registry also links all batchable endpoint tapes for
         # submit_batch's single-launch mixed admission.
         self.registry = registry if registry is not None else SchemaRegistry()

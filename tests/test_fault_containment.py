@@ -85,6 +85,13 @@ def registry():
     return reg
 
 
+@pytest.fixture(params=["interpreter", "codegen"])
+def engine(request):
+    """The sequential engine under the bounded fallback: the containment
+    contract holds for both (DESIGN.md §11)."""
+    return request.param
+
+
 class Clock:
     """Deterministic injectable clock for breaker/deadline tests."""
 
@@ -309,8 +316,10 @@ class TestGuardsAndReconciliation:
 
 
 class TestBoundedFallback:
-    def test_step_budget_times_out_fast(self):
-        reg = SchemaRegistry(fallback_max_steps=500, fallback_deadline_s=None)
+    def test_step_budget_times_out_fast(self, engine):
+        reg = SchemaRegistry(
+            engine=engine, fallback_max_steps=500, fallback_deadline_s=None
+        )
         reg.register("arr", {"type": "array", "items": {"type": "integer"}})
         big = list(range(10_000))
         t0 = time.perf_counter()
@@ -319,8 +328,12 @@ class TestBoundedFallback:
         assert v.outcome is ValidationOutcome.TIMED_OUT
         assert "budget" in v.reason
 
-    def test_wall_clock_deadline(self):
-        reg = SchemaRegistry(fallback_deadline_s=0.02, guard=GuardLimits(max_nodes=1 << 20))
+    def test_wall_clock_deadline(self, engine):
+        reg = SchemaRegistry(
+            engine=engine,
+            fallback_deadline_s=0.02,
+            guard=GuardLimits(max_nodes=1 << 20),
+        )
         reg.register("arr", {"type": "array", "items": {"type": "integer", "minimum": 0}})
         big = list(range(400_000))
         t0 = time.perf_counter()
@@ -328,10 +341,12 @@ class TestBoundedFallback:
         assert time.perf_counter() - t0 < 2.0
         assert v.outcome is ValidationOutcome.TIMED_OUT
 
-    def test_depth_bomb_structured(self):
+    def test_depth_bomb_structured(self, engine):
         # no guard: the bomb reaches the parser, which must reject in a
         # structured way (TIMED_OUT) rather than blowing the stack
-        reg = SchemaRegistry(guard=GuardLimits(max_depth=1 << 20, max_nodes=1 << 20))
+        reg = SchemaRegistry(
+            engine=engine, guard=GuardLimits(max_depth=1 << 20, max_nodes=1 << 20)
+        )
         reg.register("t", SCHEMA)
         bomb = 0
         for _ in range(50_000):
@@ -339,10 +354,10 @@ class TestBoundedFallback:
         v = reg.validate_one("t", bomb)
         assert v.outcome is ValidationOutcome.TIMED_OUT
 
-    def test_executor_depth_guard(self):
+    def test_executor_depth_guard(self, engine):
         # satellite: the sequential executor raises a structured error,
         # never RecursionError, on hostile nesting
-        validator = Validator(compile_schema({"type": "object"}))
+        validator = Validator(compile_schema({"type": "object"}), engine=engine)
         bomb = 0
         for _ in range(50_000):
             bomb = [bomb]
@@ -355,8 +370,8 @@ class TestBoundedFallback:
         assert not analyze_pattern("^x-").risky
         assert not analyze_pattern("^[a-z]{1,10}$").risky
 
-    def test_risky_pattern_times_out(self):
-        reg = SchemaRegistry()
+    def test_risky_pattern_times_out(self, engine):
+        reg = SchemaRegistry(engine=engine)
         reg.register("p", {"type": "string", "pattern": "(a+)+$"})
         subject = "a" * 28 + "!"
         t0 = time.perf_counter()
@@ -365,11 +380,48 @@ class TestBoundedFallback:
         assert v.outcome is ValidationOutcome.TIMED_OUT
         assert "backtracking" in v.reason
 
-    def test_unbounded_path_unchanged(self):
+    def test_unbounded_path_unchanged(self, engine):
         # the clean (unbounded) executor still runs engine regexes,
         # risky or not -- containment applies only under a budget
-        validator = Validator(compile_schema({"type": "string", "pattern": "(a+)+$"}))
+        validator = Validator(
+            compile_schema({"type": "string", "pattern": "(a+)+$"}), engine=engine
+        )
         assert validator.is_valid("aaa")
+
+    def test_eval_depth_bound(self, engine):
+        # a document the parser takes, nested past max_eval_depth under a
+        # recursive schema: the evaluation bound refuses it in both engines
+        reg = SchemaRegistry(
+            engine=engine, guard=GuardLimits(max_depth=1 << 20, max_nodes=1 << 20)
+        )
+        reg.register("r", {"type": "array", "items": {"$ref": "#"}})
+        deep = []
+        for _ in range(300):
+            deep = [deep]
+        v = reg.validate_one("r", deep)
+        assert v.outcome is ValidationOutcome.TIMED_OUT
+        assert reg.validate_one("r", [[[]]]).outcome is ValidationOutcome.ADMITTED
+
+    def test_fallback_rows_counter(self, engine):
+        # registry_fallback_rows_total{engine}: rows handed to a fallback
+        # validator, labelled by its engine, published once per loop
+        reg = SchemaRegistry(engine=engine)
+        reg.register("t", SCHEMA)
+        reg.register("u", {"type": "array", "uniqueItems": True})  # no tape
+        # the last row is past max_nodes: an oversize row falls back too
+        docs = _docs(24, seed=3) + [[1, 2], [1, 1], "x", {"a": 1, "c": list(range(8))}]
+        endpoints = ["t"] * 24 + ["u", "u", "u", "t"]
+        _, counts = reg.admit_mixed_ex(docs, endpoints, max_nodes=4)
+        rows = lambda e: reg.metrics.counter(
+            "registry_fallback_rows_total", engine=e
+        ).value
+        other = "interpreter" if engine == "codegen" else "codegen"
+        assert counts.fallback_validated >= 4 and counts.batch_validated > 0
+        assert rows(engine) == counts.fallback_validated
+        assert rows(other) == 0
+        reg.validate_one("u", [3])
+        assert rows(engine) == counts.fallback_validated + 1
+        assert rows(other) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -399,9 +451,10 @@ class TestCircuitBreaker:
         b.record_success()
         assert b.state == "closed" and b.allow()
 
-    def test_trips_and_recovers_through_registry(self):
+    def test_trips_and_recovers_through_registry(self, engine):
         clock = Clock()
         reg = SchemaRegistry(
+            engine=engine,
             fallback_max_steps=4,
             fallback_deadline_s=None,
             breaker=BreakerConfig(threshold=3, cooldown_s=30.0),
@@ -425,9 +478,10 @@ class TestCircuitBreaker:
         v = reg.validate_one("t", 6)
         assert v.outcome is ValidationOutcome.INVALID
 
-    def test_probe_timeout_reopens(self):
+    def test_probe_timeout_reopens(self, engine):
         clock = Clock()
         reg = SchemaRegistry(
+            engine=engine,
             fallback_max_steps=4,
             fallback_deadline_s=None,
             breaker=BreakerConfig(threshold=2, cooldown_s=5.0),
