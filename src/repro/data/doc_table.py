@@ -7,6 +7,16 @@ children of every node are *contiguous* -- property matching and item loops
 become range scans.  Key/string hashes are computed at encode time, exactly
 as the paper computes hashes during parsing (§4.1).
 
+One encoder, :func:`encode_batch`, in three steps: (1) one BFS walk per
+document appends plain Python values to flat per-batch lists, with no
+numpy call per node; (2) every key and string value is interned in a
+per-batch table, so each distinct text is hashed (8 lanes, its 8-byte
+prefix, its last byte) once per batch; (3) each column is scattered once
+into an array preallocated at (B, N), gathering hashes by text id.  A row
+that raises or runs out of budget is cut back out of the flat lists, so
+it leaves no partial writes.  The intern table lives and dies inside one
+call.  :func:`encode_document` is its one-row case.
+
 Long-string caveat: the paper resolves long-string (>31 byte) hash
 collisions with a full string comparison.  The batched executor cannot
 pointer-chase into variable-length strings, so long strings additionally
@@ -24,8 +34,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.doc_model import HashedObject
-from ..core.hashing import SHORT_LIMIT, hash_lanes, shash_bytes
-from ..core.nodetypes import TYPE_CODES
+from ..core.hashing import SHORT_LIMIT, shash_bytes
+from ..core.nodetypes import T_ARR, T_BOOL, T_NULL, T_NUM, T_OBJ, T_STR, TYPE_CODES
 from ..core.outcomes import fault_point
 
 __all__ = ["TokenTable", "encode_document", "encode_batch", "key_lanes", "TYPE_CODES"]
@@ -45,22 +55,7 @@ def _fnv64(data: bytes) -> int:
 def key_lanes(s: str) -> np.ndarray:
     """8x uint32 lanes for a key/string: the paper's semi-perfect hash, with
     FNV64 strengthening in lanes 6-7 for long strings (batch mode only)."""
-    data = s.encode("utf-8")
-    lanes = hash_lanes(shash_bytes(data))
-    if len(data) > SHORT_LIMIT:
-        fnv = _fnv64(data)
-        lanes = lanes.copy()
-        lanes[6] = (fnv >> 32) & 0xFFFFFFFF
-        lanes[7] = fnv & 0xFFFFFFFF
-    return lanes
-
-
-def _str_prefix8(data: bytes) -> Tuple[int, int]:
-    padded = data[:8].ljust(8, b"\x00")
-    return (
-        int.from_bytes(padded[:4], "big"),
-        int.from_bytes(padded[4:], "big"),
-    )
+    return _text_tables([s.encode("utf-8")])[0][0]
 
 
 @dataclass
@@ -70,7 +65,7 @@ class TokenTable:
     node_type: np.ndarray  # int8   (B, N)
     is_int: np.ndarray  # bool     (B, N)
     num: np.ndarray  # float64    (B, N)   numeric value / bool as 0,1
-    size: np.ndarray  # int32     (B, N)   str bytes / arr len / obj props
+    size: np.ndarray  # int32     (B, N)   str code points / arr len / obj props
     parent: np.ndarray  # int32   (B, N)   -1 for root
     depth: np.ndarray  # int32    (B, N)
     idx_in_parent: np.ndarray  # int32 (B, N)  array index or object slot
@@ -120,12 +115,6 @@ class TokenTable:
         return TokenTable(errors=errs, **cols)
 
 
-def _items_of(value: Any):
-    if isinstance(value, HashedObject):
-        return value.items()
-    return list(value.items())
-
-
 def encode_document(
     doc: Any,
     max_nodes: int = 256,
@@ -133,73 +122,33 @@ def encode_document(
 ) -> Optional[Dict[str, np.ndarray]]:
     """Encode one parsed JSON value into single-document columns (N,).
 
-    Returns None when the document exceeds the node or depth budget
-    (callers fall back to the sequential executor).
+    The one-row case of :func:`encode_batch`.  Returns None when the
+    document exceeds the node or depth budget (callers fall back to the
+    sequential executor).
     """
-    cols = {
-        "node_type": np.zeros(max_nodes, np.int8),
-        "is_int": np.zeros(max_nodes, bool),
-        "num": np.zeros(max_nodes, np.float64),
-        "size": np.zeros(max_nodes, np.int32),
-        "parent": np.full(max_nodes, -1, np.int32),
-        "depth": np.zeros(max_nodes, np.int32),
-        "idx_in_parent": np.full(max_nodes, -1, np.int32),
-        "child_start": np.zeros(max_nodes, np.int32),
-        "key_hash": np.zeros((max_nodes, 8), np.uint32),
-        "str_hash": np.zeros((max_nodes, 8), np.uint32),
-        "str_prefix": np.zeros((max_nodes, 2), np.uint32),
-        "str_last": np.zeros(max_nodes, np.uint32),
-    }
-    # BFS queue of (value, parent_idx, depth, key(str|None), idx_in_parent)
-    queue: List[Tuple[Any, int, int, Optional[str], int]] = [(doc, -1, 0, None, -1)]
-    count = 0
-    while queue:
-        value, parent, depth, key, idx = queue.pop(0)
-        if count >= max_nodes or depth > max_depth:
-            return None
-        i = count
-        count += 1
-        cols["parent"][i] = parent
-        cols["depth"][i] = depth
-        cols["idx_in_parent"][i] = idx
-        if key is not None:
-            cols["key_hash"][i] = key_lanes(key)
-        if value is None:
-            cols["node_type"][i] = TYPE_CODES["null"]
-        elif isinstance(value, bool):
-            cols["node_type"][i] = TYPE_CODES["boolean"]
-            cols["num"][i] = 1.0 if value else 0.0
-        elif isinstance(value, (int, float)):
-            cols["node_type"][i] = TYPE_CODES["number"]
-            cols["num"][i] = float(value)
-            cols["is_int"][i] = (
-                isinstance(value, int) or float(value).is_integer()
-            )
-        elif isinstance(value, str):
-            data = value.encode("utf-8")
-            cols["node_type"][i] = TYPE_CODES["string"]
-            cols["size"][i] = len(value)  # code points, matching len(str)
-            cols["str_hash"][i] = key_lanes(value)
-            p0, p1 = _str_prefix8(data)
-            cols["str_prefix"][i] = (p0, p1)
-            cols["str_last"][i] = data[-1] if data else 0
-        elif isinstance(value, list):
-            cols["node_type"][i] = TYPE_CODES["array"]
-            cols["size"][i] = len(value)
-            cols["child_start"][i] = count + len(queue)
-            for j, item in enumerate(value):
-                queue.append((item, i, depth + 1, None, j))
-        elif isinstance(value, (dict, HashedObject)):
-            items = _items_of(value)
-            cols["node_type"][i] = TYPE_CODES["object"]
-            cols["size"][i] = len(items)
-            cols["child_start"][i] = count + len(queue)
-            for j, (k, v) in enumerate(items):
-                queue.append((v, i, depth + 1, k, j))
-        else:
-            raise TypeError(f"unsupported JSON value {type(value)!r}")
-    cols["n_nodes"] = np.int32(count)
+    table = encode_batch([doc], max_nodes, max_depth)
+    if not table.ok[0]:
+        return None
+    cols = {k: v[0] for k, v in table.columns().items() if k not in ("n_nodes", "ok")}
+    cols["n_nodes"] = table.n_nodes[0]
     return cols
+
+
+def _text_tables(texts: List[bytes]) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Per distinct text: its 8 hash lanes (S, 8), 8-byte prefix (S, 2)
+    and last byte (S,), all uint32."""
+    n = len(texts)
+    packed = b"".join([shash_bytes(d).to_bytes(32, "big") for d in texts])
+    lanes = np.frombuffer(packed, ">u4").reshape(n, 8).astype(np.uint32)
+    for j, data in enumerate(texts):
+        if len(data) > SHORT_LIMIT:
+            fnv = _fnv64(data)
+            lanes[j, 6] = fnv >> 32
+            lanes[j, 7] = fnv & 0xFFFFFFFF
+    heads = b"".join([d[:8].ljust(8, b"\x00") for d in texts])
+    prefix = np.frombuffer(heads, ">u4").reshape(n, 2).astype(np.uint32)
+    last = np.array([d[-1] if d else 0 for d in texts], np.uint32)
+    return lanes, prefix, last
 
 
 def encode_batch(
@@ -212,6 +161,13 @@ def encode_batch(
 ) -> TokenTable:
     """Encode a batch of documents; oversize docs get ok=False rows.
 
+    One BFS walk per document appends plain Python values to flat
+    per-batch lists (the node's position in them is its row's offset
+    plus its BFS index); every key and string value is interned once per
+    batch and hashed once per distinct text; then each column is
+    scattered once into an array preallocated at (B, N).  Nothing
+    outlives the call.
+
     With ``isolate=True`` a per-document encode exception (including an
     injected ``"encode"`` fault and ``RecursionError`` on hostile
     nesting) is trapped into ``TokenTable.errors[row]`` instead of
@@ -221,37 +177,162 @@ def encode_batch(
     (defaults to the row index).
     """
     batch = len(docs)
-    stacked: Dict[str, List[np.ndarray]] = {}
-    ok = np.ones(batch, bool)
+    ok = np.zeros(batch, bool)
     n_nodes = np.zeros(batch, np.int32)
     errors: Dict[int, str] = {}
-    template = encode_document(None, max_nodes)
-    zero_cols = None
+    # one entry per node, in BFS order (appended when the node is queued)
+    parent: List[int] = []
+    depth: List[int] = []
+    idx: List[int] = []
+    raw_key: List[Any] = []
+    # one entry per node, appended when the node is dequeued
+    ntype: List[int] = []
+    # (node, value) pairs for the nodes that carry the column
+    key_at: List[int] = []
+    key_id: List[int] = []
+    str_at: List[int] = []
+    str_id: List[int] = []
+    num_at: List[int] = []
+    num: List[float] = []
+    num_int: List[bool] = []
+    box_at: List[int] = []
+    box_size: List[int] = []
+    box_start: List[int] = []
+    flat = (parent, depth, idx, raw_key, ntype, key_at, key_id, str_at,
+            str_id, num_at, num, num_int, box_at, box_size, box_start)
+    # text -> id; the batch's distinct keys and strings, as UTF-8 and in
+    # code points
+    intern: Dict[str, int] = {}
+    texts: List[bytes] = []
+    chars: List[int] = []
+
     for b, doc in enumerate(docs):
-        if isolate:
-            try:
+        marks = [len(col) for col in flat]
+        base = marks[0]
+        fits = True
+        try:
+            if isolate:
                 fault_point("encode", keys[b] if keys is not None else b)
-                cols = encode_document(doc, max_nodes, max_depth)
-            except RecursionError:
-                errors[b] = "encode recursion limit exceeded"
-                cols = None
-            except Exception as exc:  # isolated per-document fault
-                errors[b] = f"{type(exc).__name__}: {exc}"
-                cols = None
+            queue = [doc]
+            parent.append(-1)
+            depth.append(0)
+            idx.append(-1)
+            raw_key.append(None)
+            # the queue grows while it is read: BFS index i is node base + i
+            for i, value in enumerate(queue):
+                f = base + i
+                d = depth[f]
+                if i >= max_nodes or d > max_depth:
+                    fits = False
+                    break
+                k = raw_key[f]
+                if k is not None:
+                    kid = intern.get(k)
+                    if kid is None:
+                        data = k.encode("utf-8")
+                        kid = intern[k] = len(texts)
+                        texts.append(data)
+                        chars.append(len(k))
+                    key_at.append(f)
+                    key_id.append(kid)
+                if isinstance(value, str):
+                    sid = intern.get(value)
+                    if sid is None:
+                        data = value.encode("utf-8")
+                        sid = intern[value] = len(texts)
+                        texts.append(data)
+                        chars.append(len(value))
+                    ntype.append(T_STR)
+                    str_at.append(f)
+                    str_id.append(sid)
+                elif isinstance(value, (dict, HashedObject, list)):
+                    n = len(value)
+                    is_list = isinstance(value, list)
+                    ntype.append(T_ARR if is_list else T_OBJ)
+                    box_at.append(f)
+                    box_size.append(n)
+                    box_start.append(len(queue))
+                    if n:
+                        if is_list:
+                            queue.extend(value)
+                            raw_key.extend([None] * n)
+                        else:
+                            queue.extend(value.values())
+                            raw_key.extend(value.keys())
+                        parent.extend([i] * n)
+                        depth.extend([d + 1] * n)
+                        idx.extend(range(n))
+                elif value is None:
+                    ntype.append(T_NULL)
+                elif isinstance(value, bool):
+                    ntype.append(T_BOOL)
+                    num_at.append(f)
+                    num.append(1.0 if value else 0.0)
+                    num_int.append(False)
+                elif isinstance(value, (int, float)):
+                    x = float(value)
+                    ntype.append(T_NUM)
+                    num_at.append(f)
+                    num.append(x)
+                    num_int.append(isinstance(value, int) or x.is_integer())
+                else:
+                    raise TypeError(f"unsupported JSON value {type(value)!r}")
+        except RecursionError:
+            if not isolate:
+                raise
+            errors[b] = "encode recursion limit exceeded"
+            fits = False
+        except Exception as exc:  # isolated per-document fault
+            if not isolate:
+                raise
+            errors[b] = f"{type(exc).__name__}: {exc}"
+            fits = False
+        if fits:
+            ok[b] = True
+            n_nodes[b] = len(queue)
         else:
-            cols = encode_document(doc, max_nodes, max_depth)
-        if cols is None:
-            ok[b] = False  # budget overflow (fallback) or isolated error row
-            if zero_cols is None:
-                zero_cols = {
-                    k: np.zeros_like(v)
-                    for k, v in template.items()
-                    if k != "n_nodes"
-                }
-            cols = dict(zero_cols)
-            cols["n_nodes"] = np.int32(0)
-        n_nodes[b] = cols.pop("n_nodes")
-        for k, v in cols.items():
-            stacked.setdefault(k, []).append(v)
-    arrays = {k: np.stack(v) for k, v in stacked.items()}
-    return TokenTable(n_nodes=n_nodes, ok=ok, errors=errors, **arrays)
+            for col, mark in zip(flat, marks):
+                del col[mark:]
+
+    shape = (batch, max_nodes)
+    cols = {
+        "node_type": np.zeros(shape, np.int8),
+        "is_int": np.zeros(shape, bool),
+        "num": np.zeros(shape, np.float64),
+        "size": np.zeros(shape, np.int32),
+        "parent": np.full(shape, -1, np.int32),
+        "depth": np.zeros(shape, np.int32),
+        "idx_in_parent": np.full(shape, -1, np.int32),
+        "child_start": np.zeros(shape, np.int32),
+        "key_hash": np.zeros(shape + (8,), np.uint32),
+        "str_hash": np.zeros(shape + (8,), np.uint32),
+        "str_prefix": np.zeros(shape + (2,), np.uint32),
+        "str_last": np.zeros(shape, np.uint32),
+    }
+    # dest[f]: flat (B * N) position of node f; rows that did not encode
+    # hold no nodes
+    dest = np.flatnonzero(np.arange(max_nodes) < n_nodes[:, None])
+
+    def scatter(name: str, at: Optional[List[int]], values: Any) -> None:
+        col = cols[name]
+        col.reshape((-1,) + col.shape[2:])[dest if at is None else dest[at]] = values
+
+    scatter("node_type", None, ntype)
+    scatter("parent", None, parent)
+    scatter("depth", None, depth)
+    scatter("idx_in_parent", None, idx)
+    scatter("num", num_at, num)
+    scatter("is_int", num_at, num_int)
+    scatter("size", box_at, box_size)
+    scatter("child_start", box_at, box_start)
+    lanes, prefix, last = _text_tables(texts)
+    sids = np.asarray(str_id, np.intp)
+    scatter("key_hash", key_at, lanes[np.asarray(key_id, np.intp)])
+    scatter("str_hash", str_at, lanes[sids])
+    scatter("str_prefix", str_at, prefix[sids])
+    scatter("str_last", str_at, last[sids])
+    scatter("size", str_at, np.asarray(chars, np.int32)[sids])
+    # rows that did not encode are all zero, -1 fills included
+    cols["parent"][~ok] = 0
+    cols["idx_in_parent"][~ok] = 0
+    return TokenTable(n_nodes=n_nodes, ok=ok, errors=errors, **cols)

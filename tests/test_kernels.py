@@ -171,9 +171,9 @@ class TestAssertionEval:
         assert int(kops.assertion_eval(nodes, asrts)[0, 0]) == 1
 
     def test_str_prefix_check(self):
-        from repro.data.doc_table import _str_prefix8
+        from repro.data.doc_table import _text_tables
 
-        p0, p1 = _str_prefix8(b"x-hello")
+        p0, p1 = (int(p) for p in _text_tables([b"x-hello"])[1][0])
         nodes = {
             "type": jnp.asarray([4], jnp.int32),
             "is_int": jnp.zeros(1, jnp.int32),

@@ -137,18 +137,23 @@ def test_armed_encode_batch_hashes_with_the_plain_key_lanes(monkeypatch):
     docs = [{"k": "v" * (i % 40), "n": [i, str(i)]} for i in range(64)]
     plain = encode_batch(docs, max_nodes=MAX_NODES)
     calls = []
-    real = doc_table.key_lanes
+    real = doc_table._text_tables
 
-    def counted(s):
-        calls.append(s)
-        return real(s)
+    def counted(texts):
+        calls.append(list(texts))
+        return real(texts)
 
-    monkeypatch.setattr(doc_table, "key_lanes", counted)
+    monkeypatch.setattr(doc_table, "_text_tables", counted)
     with Tracer() as tr:
         armed = encode_batch(docs, max_nodes=MAX_NODES)
     assert tr.recorded == 0  # no span inside the encoder
-    # two keys and two strings per document, each hashed once
-    assert len(calls) == 4 * len(docs)
+    # one hashing pass per batch: two keys, 40 distinct "v" runs and 64
+    # numerals, each hashed once
+    (texts,) = calls
+    assert len(texts) == len(set(texts)) == 2 + 40 + 64
+    lanes = real(texts)[0]
+    for text, row in zip(texts, lanes):
+        np.testing.assert_array_equal(row, doc_table.key_lanes(text.decode()))
     for k, v in plain.columns().items():
         np.testing.assert_array_equal(v, armed.columns()[k])
 
