@@ -86,7 +86,7 @@ from .tape import (
 __all__ = ["BatchValidator"]
 
 _BIG = jnp.int32(2**30)
-_CIRC_FLAG = 1 << 20  # packed circuit-membership bit in asrt_gcode
+_CIRC_FLAG = 1 << 20  # packed circuit-membership bit in the window gcode column
 
 
 def _group_circ_map(tape: LocationTape) -> np.ndarray:
@@ -169,13 +169,96 @@ def _circuit_static_wiring(tape: LocationTape):
     }
 
 
-def _tape_consts(tape: LocationTape) -> Dict[str, jnp.ndarray]:
+def _window_table(tape: LocationTape, n_window: int) -> np.ndarray:
+    """Per-location assertion windows as packed uint32 rows, (L + 1, 15 W).
+
+    Row ``l`` holds location ``l``'s W window slots column by column:
+    ``op``, ``f0`` (float32 bits), ``i0``, ``i1``, ``u0``, ``u1``,
+    ``gcode`` (W words each), then the 8 hash lanes slot by slot (8 W
+    words).  Slot ``s`` is assertion row ``loc_asrt_start[l] + s``; slots
+    at or past ``loc_asrt_len[l]`` carry ``op = -1`` and zeros.  The last
+    row is all masked slots, the row of every untracked node.  A launch
+    reads a node's whole window with one row gather instead of one
+    element gather per column and slot.
+    """
     # the packed gcode column reserves bit 20 for circuit membership: a
     # linked tape accumulating that many distinct OR-group ids must fail
     # loudly, never silently misdecode enum rows as circuit rows
     assert int(np.asarray(tape.asrt_group).max(initial=0)) < _CIRC_FLAG, (
         "OR-group id space exceeds the gcode circuit-flag bit"
     )
+    # op -1 marks a masked slot, so no real row may carry it
+    assert int(np.asarray(tape.asrt_op).min(initial=0)) >= 0
+    gcode = np.asarray(tape.asrt_group) + np.where(
+        np.asarray(tape.asrt_circ) >= 0, _CIRC_FLAG, 0
+    )
+    start = np.append(np.asarray(tape.loc_asrt_start), 0)
+    length = np.append(np.asarray(tape.loc_asrt_len), 0)
+    slots = np.arange(n_window)
+    rows = np.minimum(start[:, None] + slots, len(tape.asrt_op) - 1)  # (L+1, W)
+    valid = slots < length[:, None]
+
+    def col(values, dtype, fill=0):
+        x = np.asarray(values).astype(dtype)[rows]
+        return np.where(valid, x, np.asarray(fill, dtype)).view(np.uint32)
+
+    hash_w = np.where(valid[..., None], np.asarray(tape.asrt_hash, np.uint32)[rows], 0)
+    return np.concatenate(
+        [
+            col(tape.asrt_op, np.int32, -1),
+            col(tape.asrt_f0, np.float32),
+            col(tape.asrt_i0, np.int32),
+            col(tape.asrt_i1, np.int32),
+            col(tape.asrt_u0, np.uint32),
+            col(tape.asrt_u1, np.uint32),
+            col(gcode, np.int32),
+            hash_w.reshape(len(start), n_window * 8),
+        ],
+        axis=1,
+    )
+
+
+def _candidate_table(tape: LocationTape, k_cand: int) -> np.ndarray:
+    """Per-run-start candidate rows as packed int32 rows, (M + 1, 4 K).
+
+    Row ``r`` holds psort rows ``r .. r + K - 1`` column by column:
+    ``psort_owner``, ``psort_child_loc``, ``psort_required_slot``,
+    ``psort_orig_row`` (K words each).  Slots at or past
+    ``psort_run_len[r]`` carry owner -1 and zeros; the last row, read by
+    nodes with no candidate run, is all such slots.
+    """
+    run_len = np.append(np.asarray(tape.psort_run_len), 0)
+    k = np.arange(k_cand)
+    rows = np.minimum(np.arange(len(run_len))[:, None] + k, len(tape.psort_owner) - 1)
+    valid = k < run_len[:, None]
+    return np.concatenate(
+        [
+            np.where(valid, np.asarray(tape.psort_owner)[rows], -1),
+            np.where(valid, np.asarray(tape.psort_child_loc)[rows], 0),
+            np.where(valid, np.asarray(tape.psort_required_slot)[rows], 0),
+            np.where(valid, np.asarray(tape.psort_orig_row)[rows], 0),
+        ],
+        axis=1,
+    ).astype(np.int32)
+
+
+def _segment_tables(tape: LocationTape, m_hat: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Per-member psort segments: ``(hash (S, 8, M-hat) uint32, row (S,
+    M-hat) int32)``.  Member ``s``'s slot ``j`` is psort row
+    ``member_prop_start[s] + j``; slots at or past ``member_prop_len[s]``
+    carry row ``_BIG`` (never a match) and zero lanes.  Each document
+    reads its member's segment with one row gather."""
+    start = np.asarray(tape.member_prop_start)
+    j = np.arange(m_hat)
+    rows = np.minimum(start[:, None] + j, len(tape.psort_hash) - 1)  # (S, Mh)
+    valid = j < np.asarray(tape.member_prop_len)[:, None]
+    seg_hash = np.where(valid[..., None], np.asarray(tape.psort_hash, np.uint32)[rows], 0)
+    seg_row = np.where(valid, rows, int(_BIG)).astype(np.int32)
+    return np.ascontiguousarray(seg_hash.transpose(0, 2, 1)), seg_row
+
+
+def _tape_consts(tape: LocationTape, n_window: int, k_cand: int, m_hat: int) -> Dict[str, jnp.ndarray]:
+    seg_hash, seg_row = _segment_tables(tape, m_hat)
     return {
         "prop_owner": jnp.asarray(tape.prop_owner),
         "prop_hash": jnp.asarray(tape.prop_hash),
@@ -183,10 +266,10 @@ def _tape_consts(tape: LocationTape) -> Dict[str, jnp.ndarray]:
         "prop_required_slot": jnp.asarray(tape.prop_required_slot),
         "psort_hash": jnp.asarray(tape.psort_hash),
         "psort_owner": jnp.asarray(tape.psort_owner),
-        "psort_child_loc": jnp.asarray(tape.psort_child_loc),
-        "psort_required_slot": jnp.asarray(tape.psort_required_slot),
-        "psort_orig_row": jnp.asarray(tape.psort_orig_row),
-        "psort_run_len": jnp.asarray(tape.psort_run_len),
+        # packed tape-constant rows keyed by what varies per node: the
+        # candidate run by its start row, the assertion window by
+        # location, the psort segment by member (DESIGN.md §4, §5, §8)
+        "cand": jnp.asarray(_candidate_table(tape, k_cand)),
         "prefix_loc": jnp.asarray(tape.prefix_loc),
         # packed per-location structural row: one gather per depth
         # iteration instead of six (addl, closed, item, item_start,
@@ -203,8 +286,8 @@ def _tape_consts(tape: LocationTape) -> Dict[str, jnp.ndarray]:
             axis=1,
         ),
         "loc_required_mask": jnp.asarray(tape.loc_required_mask.astype(np.int32)),
-        "loc_asrt_start": jnp.asarray(tape.loc_asrt_start),
-        "loc_asrt_len": jnp.asarray(tape.loc_asrt_len),
+        "loc_asrt_start": jnp.asarray(tape.loc_asrt_start),  # explain's row ids
+        "win": jnp.asarray(_window_table(tape, n_window)),
         "asrt_owner": jnp.asarray(tape.asrt_owner),
         "asrt_op": jnp.asarray(tape.asrt_op),
         "asrt_group": jnp.asarray(tape.asrt_group),
@@ -215,15 +298,6 @@ def _tape_consts(tape: LocationTape) -> Dict[str, jnp.ndarray]:
         "asrt_u1": jnp.asarray(tape.asrt_u1),
         "asrt_hash": jnp.asarray(tape.asrt_hash),
         "asrt_circ": jnp.asarray(tape.asrt_circ),
-        # group id + circuit-membership flag packed into one column so
-        # the windowed path pays ONE gather for both (group ids stay far
-        # below the flag bit)
-        "asrt_gcode": jnp.asarray(
-            (
-                np.asarray(tape.asrt_group)
-                + np.where(np.asarray(tape.asrt_circ) >= 0, _CIRC_FLAG, 0)
-            ).astype(np.int32)
-        ),
         "psort_member": jnp.asarray(tape.psort_member),
         # a frontier root (degenerate: the unroll budget died at the
         # root) must seed documents with the sentinel, not location 0
@@ -233,8 +307,8 @@ def _tape_consts(tape: LocationTape) -> Dict[str, jnp.ndarray]:
             )
         ),
         "member_horizons": jnp.asarray(tape.member_horizons),
-        "member_prop_start": jnp.asarray(tape.member_prop_start),
-        "member_prop_len": jnp.asarray(tape.member_prop_len),
+        "seg_hash": jnp.asarray(seg_hash),
+        "seg_row": jnp.asarray(seg_row),
     }
 
 
@@ -311,7 +385,7 @@ class BatchValidator:
         # common case) statically skip every circuit op.
         self.n_circuits = tape.n_circuits
         self._circuits = _circuit_static_wiring(tape)
-        self._consts = _tape_consts(tape)
+        self._consts = _tape_consts(tape, self.n_window, self.k_cand, self.m_hat)
         self._fn = jax.jit(
             _named(
                 _validate_batch,
@@ -632,7 +706,7 @@ def _propagate_locations(
     member = jnp.repeat(schema_ids.astype(jnp.int32), N)  # (B*N,)
     loc = jnp.where(
         jnp.arange(B * N, dtype=jnp.int32) % N == 0,
-        consts["roots"][member],
+        jnp.repeat(consts["roots"][schema_ids], N),
         jnp.int32(-1),
     )
     acquired = jnp.zeros(B * N, jnp.int32)  # required-slot bits per object
@@ -666,30 +740,26 @@ def _propagate_locations(
             # linked tape on the jnp path: member-windowed pass -- each
             # node scans only its member's psort segment (<= M-hat rows),
             # so per-node work tracks the *largest* member instead of the
-            # member sum.  Runs never span members, so the minimal
-            # matching row in the segment is the run start, exactly as
-            # the kernel branch returns
-            seg_start = consts["member_prop_start"][member]  # (BN,)
-            seg_len = consts["member_prop_len"][member]
-            m_idx = jnp.arange(m_hat, dtype=jnp.int32)[None, :]  # (1, Mh)
-            seg_rows = jnp.clip(seg_start[:, None] + m_idx, 0, M - 1)  # (BN, Mh)
-            row_ok = (m_idx < seg_len[:, None]) & is_member_node[:, None]
-            lane_eq = jnp.all(
-                key_hash[:, None, :] == consts["psort_hash"][seg_rows], axis=-1
-            )
-            row_masked = jnp.where(lane_eq & row_ok, seg_rows, _BIG)
-            first_row = jnp.min(row_masked, axis=1)
-            first = jnp.where(first_row < _BIG, first_row, jnp.int32(-1))
-        has_cand = first >= 0
-        safe_first = jnp.where(has_cand, first, 0)
-        run_len = jnp.where(has_cand, consts["psort_run_len"][safe_first], 0)
-        k_arange = jnp.arange(k_cand, dtype=jnp.int32)[None, :]  # (1, K)
-        cand_rows = jnp.clip(safe_first[:, None] + k_arange, 0, M - 1)  # (BN, K)
-        cand_valid = k_arange < run_len[:, None]
-        cand_owner = jnp.where(cand_valid, consts["psort_owner"][cand_rows], -1)
-        cand_child = consts["psort_child_loc"][cand_rows]
-        cand_slot = consts["psort_required_slot"][cand_rows]
-        cand_orig = consts["psort_orig_row"][cand_rows]
+            # member sum.  Each document reads its segment once; runs
+            # never span members, so the minimal matching row in the
+            # segment is the run start, exactly as the kernel branch
+            # returns
+            seg_hash = consts["seg_hash"][schema_ids]  # (B, 8, Mh)
+            seg_row = consts["seg_row"][schema_ids]  # (B, Mh), _BIG past the segment
+            q = key_hash.reshape(B, N, 8)
+            hit = is_member_node.reshape(B, N)[:, :, None]
+            for lane in range(8):
+                hit = hit & (q[:, :, lane, None] == seg_hash[:, None, lane, :])
+            first_row = jnp.min(jnp.where(hit, seg_row[:, None, :], _BIG), axis=2)
+            first = jnp.where(first_row < _BIG, first_row, jnp.int32(-1)).reshape(B * N)
+        # one packed row per node: its candidate run (the table's last
+        # row, all owner -1, for nodes with no run)
+        cand = consts["cand"][jnp.where(first >= 0, first, M)]  # (BN, 4K)
+        cand_owner = cand[:, :k_cand]
+        cand_valid = cand_owner >= 0
+        cand_child = cand[:, k_cand : 2 * k_cand]
+        cand_slot = cand[:, 2 * k_cand : 3 * k_cand]
+        cand_orig = cand[:, 3 * k_cand :]
 
     # the required-bit contribution of every node is known the moment its
     # own depth iteration resolves it -- accumulate elementwise in the
@@ -704,13 +774,15 @@ def _propagate_locations(
         is_member = at_depth & is_member_node
         if layout == "csr":
             # owner-equality over the K pre-gathered candidates; ties
-            # break to the minimal original row (dense-path semantics)
+            # break to the minimal original row (dense-path semantics).
+            # Original rows are distinct, so the pick is one slot: read
+            # it by an elementwise select, not a gather per node
             m = cand_valid & (cand_owner == parent_loc[:, None])
-            orig_masked = jnp.where(m, cand_orig, _BIG)
-            best_k = jnp.argmin(orig_masked, axis=1)
-            matched = jnp.min(orig_masked, axis=1) < _BIG
-            child_loc_m = jnp.take_along_axis(cand_child, best_k[:, None], axis=1)[:, 0]
-            slot_m = jnp.take_along_axis(cand_slot, best_k[:, None], axis=1)[:, 0]
+            best = jnp.min(jnp.where(m, cand_orig, _BIG), axis=1)
+            matched = best < _BIG
+            pick = m & (cand_orig == best[:, None])
+            child_loc_m = jnp.sum(jnp.where(pick, cand_child, 0), axis=1)
+            slot_m = jnp.sum(jnp.where(pick, cand_slot, 0), axis=1)
         else:
             q_owner = jnp.where(is_member & (parent_loc >= 0), parent_loc, jnp.int32(-1))
             row = kops.hash_match(
@@ -828,30 +900,29 @@ def _assertions_csr(
     per-window intermediates so the first-failure pass can argmax over
     them without recomputing.
     """
-    A = consts["asrt_op"].shape[0]
+    W = n_window
     tracked = loc >= 0
-    loc_safe = jnp.where(tracked, loc, 0)
-    w_start = consts["loc_asrt_start"][loc_safe]
-    w_len = jnp.where(tracked, consts["loc_asrt_len"][loc_safe], 0)
-    slots = jnp.arange(n_window, dtype=jnp.int32)[None, :]  # (1, W)
-    w_rows = jnp.clip(w_start[:, None] + slots, 0, A - 1)  # (BN, W)
-    w_valid = slots < w_len[:, None]  # (BN, W) == "applies"
+    # one packed row per node: its location's whole window (the table's
+    # last row, all masked slots, for untracked nodes)
+    win = consts["win"][jnp.where(tracked, loc, consts["win"].shape[0] - 1)]
+    as_i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
     w_cols = {
-        "op": jnp.where(w_valid, consts["asrt_op"][w_rows], -1),
-        "f0": consts["asrt_f0"][w_rows],
-        "i0": consts["asrt_i0"][w_rows],
-        "i1": consts["asrt_i1"][w_rows],
-        "u0": consts["asrt_u0"][w_rows],
-        "u1": consts["asrt_u1"][w_rows],
-        "hash": consts["asrt_hash"][w_rows],
+        "op": as_i32(win[:, 0:W]),  # -1 on masked slots
+        "f0": jax.lax.bitcast_convert_type(win[:, W : 2 * W], jnp.float32),
+        "i0": as_i32(win[:, 2 * W : 3 * W]),
+        "i1": as_i32(win[:, 3 * W : 4 * W]),
+        "u0": win[:, 4 * W : 5 * W],
+        "u1": win[:, 5 * W : 6 * W],
+        "hash": win[:, 7 * W :].reshape(-1, W, 8),
     }
     passes = kops.assertion_eval_window(
         node_cols, w_cols, use_pallas=use_pallas
     ).astype(bool)  # (BN, W)
 
-    gcode = jnp.where(w_valid, consts["asrt_gcode"][w_rows], 0)
+    w_valid = w_cols["op"] >= 0  # == "applies"
+    gcode = as_i32(win[:, 6 * W : 7 * W])  # 0 on masked slots
     grp = gcode & jnp.int32(_CIRC_FLAG - 1)
-    in_circ = gcode >= _CIRC_FLAG  # constant-folds False on circuit-free tapes
+    in_circ = gcode >= _CIRC_FLAG  # rows wired to a circuit
     is_and = w_valid & (grp == 0) & ~in_circ
     and_ok = jnp.all(jnp.where(is_and, passes, True), axis=1)
 
@@ -866,8 +937,10 @@ def _assertions_csr(
     asrt_ok = and_ok & or_ok
 
     if detail is not None:
+        slots = jnp.arange(W, dtype=jnp.int32)[None, :]
+        w_start = consts["loc_asrt_start"][jnp.where(tracked, loc, 0)]
         detail.update(
-            w_rows=w_rows,
+            w_rows=w_start[:, None] + slots,  # read at applying slots only
             passes=passes,
             in_circ=in_circ,
             is_and=is_and,
