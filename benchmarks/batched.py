@@ -3,14 +3,12 @@
 Measures (a) what fraction of the benchmark corpus compiles to the
 structural-subset tensor tape (the batch fast path), and (b) throughput of
 the batched executor on an API-gateway-style request schema at increasing
-batch sizes, comparing the historical **dense** layout (hash_match per
-depth iteration + full (B*N x A) assertion matrix) against the
-**owner-sorted CSR** layout (one hoisted hash pass + (B*N x A-hat)
+batch sizes (one hoisted hash pass + owner-sorted (B*N x A-hat) assertion
 windows).  jnp path on CPU; the Pallas path is validated separately in
 tests with interpret=True.
 
-Emits ``results/BENCH_batched.json`` -- docs/s per batch size for both
-layouts, the tape-coverage fraction, and the per-tape A-hat/K constants --
+Emits ``results/BENCH_batched.json`` -- docs/s per batch size, the
+tape-coverage fraction, and the per-tape A-hat/K constants --
 so the perf trajectory stays machine-readable across PRs.
 """
 
@@ -72,10 +70,7 @@ def run(report: Dict[str, object]) -> List[str]:
     tape, reason = try_build_tape(compiled)
     assert tape is not None, f"request schema must be batchable: {reason}"
     seq = Validator(compiled)
-    executors = {
-        "dense": BatchValidator(tape, use_pallas=False, layout="dense"),
-        "csr": BatchValidator(tape, use_pallas=False, layout="csr"),
-    }
+    bv = BatchValidator(tape, use_pallas=False)
 
     import random
 
@@ -111,23 +106,17 @@ def run(report: Dict[str, object]) -> List[str]:
             "sequential_us_per_doc": t_seq / batch * 1e6,
             "encode_us_per_doc": t_encode / batch * 1e6,
         }
-        for name, bv in executors.items():
-            bv.validate(table)  # warm the jit
-            t0 = time.perf_counter()
-            valid, decided = bv.validate(table)
-            t_batch = time.perf_counter() - t0
-            assert all(
-                bool(v) == r for v, r, d in zip(valid, seq_results, decided) if d
-            )
-            row[f"{name}_docs_per_s"] = batch / t_batch
-            row[f"{name}_us_per_doc"] = t_batch / batch * 1e6
-        row["csr_speedup_vs_dense"] = row["csr_docs_per_s"] / row["dense_docs_per_s"]
+        bv.validate(table)  # warm the jit
+        t0 = time.perf_counter()
+        valid, decided = bv.validate(table)
+        t_batch = time.perf_counter() - t0
+        assert all(bool(v) == r for v, r, d in zip(valid, seq_results, decided) if d)
+        row["csr_docs_per_s"] = batch / t_batch
+        row["csr_us_per_doc"] = t_batch / batch * 1e6
         rows.append(row)
         lines.append(
             f"batched/request_validation_b{batch},{row['csr_us_per_doc']:.2f},"
-            f"dense_us={row['dense_us_per_doc']:.2f};"
-            f"seq_us={row['sequential_us_per_doc']:.2f};"
-            f"csr_x_dense={row['csr_speedup_vs_dense']:.2f}"
+            f"seq_us={row['sequential_us_per_doc']:.2f}"
         )
 
     payload = {

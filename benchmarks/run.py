@@ -15,8 +15,8 @@ One module per paper table/figure:
 
 Prints ``name,us_per_call,derived`` CSV lines and writes the full report
 to results/bench_report.json.  The batched module additionally emits
-results/BENCH_batched.json (dense vs owner-sorted-CSR docs/s per batch
-size + tape coverage) for machine-readable perf tracking across PRs.
+results/BENCH_batched.json (CSR executor docs/s per batch size + tape
+coverage) for machine-readable perf tracking across PRs.
 Every selected module runs; the harness exits non-zero if any raised.
 """
 

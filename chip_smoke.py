@@ -208,9 +208,7 @@ def run(cfg, *, seed: int, n_docs: int = N_DOCS) -> dict:
     check(bool((ids >= 0).all()), "every gateway endpoint must ride the linked tape")
     bv = reg.batch_validator()
     check(bv.use_pallas, "the registry's launch must use the Pallas kernels")
-    ref_bv = BatchValidator(
-        reg.linked_tape(), max_depth=reg.max_depth, use_pallas=False, layout=reg.layout
-    )
+    ref_bv = BatchValidator(reg.linked_tape(), max_depth=reg.max_depth, use_pallas=False)
     with phase("launch_pallas"):
         valid, decided, _ = bv.validate_ex(table, ids)
     with phase("launch_jnp"):

@@ -26,8 +26,7 @@ bit-identically to per-schema dispatch (DESIGN.md §8).  The pipeline:
    the windowed ``assertion_eval`` kernel computes the (nodes x A-hat)
    pass matrix; enum OR-groups reduce with a segmented scan over the
    window (groups are contiguous by construction).  O(N*A-hat) memory and
-   compute instead of the dense O(N*A) matrix plus a rank-3 (N, A, G)
-   one-hot reduction.
+   compute.
 3b. **Circuit reduce** (DESIGN.md §10) -- rows wired to logical-applicator
    circuits (``anyOf``/``oneOf``/``not``/``if`` over the scalar subset)
    are excluded from the plain AND/OR reduction; per-document *anchor*
@@ -46,10 +45,6 @@ bit-identically to per-schema dispatch (DESIGN.md §8).  The pipeline:
    recursion outran the tape's $ref-unroll budget carry ``LOC_FRONTIER``
    nodes and are likewise undecided (``validate_ex`` exposes the flag so
    callers can count those ``unroll_overflow`` fallbacks separately).
-
-``layout="dense"`` keeps the historical full-matrix path (hash_match per
-depth iteration + dense assertion matrix) for apples-to-apples
-benchmarking; both layouts produce bit-identical (valid, decided).
 
 The per-document fail-fast of the sequential engine becomes batch-level
 work (§2.3 short-circuiting has no analogue across a converged batch); the
@@ -89,31 +84,14 @@ _BIG = jnp.int32(2**30)
 _CIRC_FLAG = 1 << 20  # packed circuit-membership bit in the window gcode column
 
 
-def _group_circ_map(tape: LocationTape) -> np.ndarray:
-    """OR-group id -> owning circuit node (-1 for plain enum groups).
-
-    All rows of one group share a circuit by construction (a group is
-    emitted by a single enum lowering), so any row of the group may
-    supply the mapping.
-    """
-    groups = np.asarray(tape.asrt_group)
-    circ = np.asarray(tape.asrt_circ)
-    n_groups = (int(groups.max()) + 1) if groups.size else 1
-    out = np.full(max(1, n_groups), -1, np.int32)
-    for g, c in zip(groups.tolist(), circ.tolist()):
-        if g > 0 and c >= 0:
-            out[g] = c
-    return out
-
-
 def _circuit_leaf_units(tape: LocationTape):
     """Static circuit-leaf wiring: which row/group feeds which node.
 
     Returns ``(and_units, group_units)``: AND units are
-    ``(circ, owner_loc, row, window_slot)`` for plain circuit rows, group
-    units ``(circ, owner_loc, group_id, window_slot_of_start)`` for
-    circuit enum groups (rows are (owner, group)-sorted, so the first row
-    of a group is its window start).  Everything here is compile-time.
+    ``(circ, owner_loc, window_slot)`` for plain circuit rows, group
+    units ``(circ, owner_loc, window_slot_of_start)`` for circuit enum
+    groups (rows are (owner, group)-sorted, so the first row of a group
+    is its window start).  Everything here is compile-time.
     """
     owner = np.asarray(tape.asrt_owner)
     grp = np.asarray(tape.asrt_group)
@@ -129,10 +107,10 @@ def _circuit_leaf_units(tape: LocationTape):
         g = int(grp[r])
         s = r - int(start[o])
         if g == 0:
-            and_units.append((c, o, r, s))
+            and_units.append((c, o, s))
         elif g not in seen_groups:
             seen_groups.add(g)
-            group_units.append((c, o, g, s))
+            group_units.append((c, o, s))
     return tuple(and_units), tuple(group_units)
 
 
@@ -157,9 +135,6 @@ def _circuit_static_wiring(tape: LocationTape):
         "kind": np.asarray(tape.circ_kind, np.int32),
         "parent": np.asarray(tape.circ_parent, np.int32),
         "owner": circ_owner,
-        # OR-group id -> owning circuit (-1 plain), for the dense
-        # layout's group-level reduction (rows of one group share it)
-        "group_circ": _group_circ_map(tape),
         "and_units": and_units,
         "group_units": group_units,
         "owner_locs": owner_locs,
@@ -260,10 +235,6 @@ def _segment_tables(tape: LocationTape, m_hat: int) -> Tuple[np.ndarray, np.ndar
 def _tape_consts(tape: LocationTape, n_window: int, k_cand: int, m_hat: int) -> Dict[str, jnp.ndarray]:
     seg_hash, seg_row = _segment_tables(tape, m_hat)
     return {
-        "prop_owner": jnp.asarray(tape.prop_owner),
-        "prop_hash": jnp.asarray(tape.prop_hash),
-        "prop_child_loc": jnp.asarray(tape.prop_child_loc),
-        "prop_required_slot": jnp.asarray(tape.prop_required_slot),
         "psort_hash": jnp.asarray(tape.psort_hash),
         "psort_owner": jnp.asarray(tape.psort_owner),
         # packed tape-constant rows keyed by what varies per node: the
@@ -288,16 +259,6 @@ def _tape_consts(tape: LocationTape, n_window: int, k_cand: int, m_hat: int) -> 
         "loc_required_mask": jnp.asarray(tape.loc_required_mask.astype(np.int32)),
         "loc_asrt_start": jnp.asarray(tape.loc_asrt_start),  # explain's row ids
         "win": jnp.asarray(_window_table(tape, n_window)),
-        "asrt_owner": jnp.asarray(tape.asrt_owner),
-        "asrt_op": jnp.asarray(tape.asrt_op),
-        "asrt_group": jnp.asarray(tape.asrt_group),
-        "asrt_f0": jnp.asarray(tape.asrt_f0.astype(np.float32)),
-        "asrt_i0": jnp.asarray(tape.asrt_i0),
-        "asrt_i1": jnp.asarray(tape.asrt_i1),
-        "asrt_u0": jnp.asarray(tape.asrt_u0),
-        "asrt_u1": jnp.asarray(tape.asrt_u1),
-        "asrt_hash": jnp.asarray(tape.asrt_hash),
-        "asrt_circ": jnp.asarray(tape.asrt_circ),
         "psort_member": jnp.asarray(tape.psort_member),
         # a frontier root (degenerate: the unroll budget died at the
         # root) must seed documents with the sentinel, not location 0
@@ -331,15 +292,11 @@ class BatchValidator:
         *,
         max_depth: int = 16,
         use_pallas: bool = True,
-        layout: str = "csr",
         metrics=None,
     ):
-        if layout not in ("csr", "dense"):
-            raise ValueError(f"unknown layout {layout!r}")
         self.tape = tape
         self.max_depth = max_depth
         self.use_pallas = use_pallas
-        self.layout = layout
         # optional MetricRegistry (obs/metrics.py): children are cached
         # here once so the per-launch hot path is attribute adds gated on
         # one ``is not None`` check (DESIGN.md §12)
@@ -386,24 +343,23 @@ class BatchValidator:
         self.n_circuits = tape.n_circuits
         self._circuits = _circuit_static_wiring(tape)
         self._consts = _tape_consts(tape, self.n_window, self.k_cand, self.m_hat)
-        self._fn = jax.jit(
-            _named(
-                _validate_batch,
-                consts=self._consts,
-                max_depth=max_depth,
-                max_loc_depth=tape.max_loc_depth,
-                use_pallas=use_pallas,
-                layout=layout,
-                n_window=self.n_window,
-                k_cand=self.k_cand,
-                m_hat=self.m_hat,
-                n_members=tape.n_members,
-                has_frontier=self.has_frontier,
-                circuits=self._circuits,
-                n_circuits=self.n_circuits,
-            )
+        static = dict(
+            consts=self._consts,
+            max_depth=max_depth,
+            max_loc_depth=tape.max_loc_depth,
+            use_pallas=use_pallas,
+            n_window=self.n_window,
+            k_cand=self.k_cand,
+            n_members=tape.n_members,
+            circuits=self._circuits,
+            n_circuits=self.n_circuits,
         )
-        self._explain_fn = None  # lazily jitted by explain_batch
+        self._fn = jax.jit(
+            _named(_validate_batch, has_frontier=self.has_frontier, **static)
+        )
+        # traced on its first call only, so traffic that never asks for
+        # explanations never compiles it
+        self._explain_fn = jax.jit(_named(_explain_batch, **static))
 
     def validate(self, table, schema_ids=None) -> Tuple[np.ndarray, np.ndarray]:
         """Returns (valid, decided) boolean arrays of shape (B,).
@@ -599,28 +555,10 @@ class BatchValidator:
         explain launch is a separate jitted function, so ``explain=False``
         traffic never pays for it.
         """
-        if self.layout != "csr":
-            raise NotImplementedError("explain_batch requires the csr layout")
         B = table.batch
         ids = self._normalize_ids(B, schema_ids)
         if docs is not None and len(docs) != B:
             raise ValueError(f"{len(docs)} docs for batch of {B}")
-        if self._explain_fn is None:
-            self._explain_fn = jax.jit(
-                _named(
-                    _explain_batch,
-                    consts=self._consts,
-                    max_depth=self.max_depth,
-                    max_loc_depth=self.tape.max_loc_depth,
-                    use_pallas=self.use_pallas,
-                    n_window=self.n_window,
-                    k_cand=self.k_cand,
-                    m_hat=self.m_hat,
-                    n_members=self.tape.n_members,
-                    circuits=self._circuits,
-                    n_circuits=self.n_circuits,
-                )
-            )
         before = self._compiles.snapshot()
         with _span("executor.explain", batch=B) as sp:
             cols = {k: jnp.asarray(v) for k, v in table.columns().items()}
@@ -678,9 +616,7 @@ def _propagate_locations(
     *,
     loop_depth: int,
     use_pallas: bool,
-    layout: str,
     k_cand: int,
-    m_hat: int,
     n_members: int,
 ):
     """Assign every node a schema location; returns (loc, acquired, aux).
@@ -718,48 +654,47 @@ def _propagate_locations(
     is_member_node = is_real & (parent_type == _T_OBJ)
     is_item_node = is_real & (parent_type == _T_ARR)
 
-    if layout == "csr":
-        # -- hoisted single hash pass: find each object-member node's
-        # candidate-run start in its schema's hash-sorted property rows
-        M = consts["psort_owner"].shape[0]
-        if n_members == 1 or use_pallas:
-            # hash_match kernel over the whole table, owner = the row's
-            # member id (all zeros on a single tape): the kernel's minimal
-            # matching row within the querying document's member is its
-            # run start.  Streamed/blocked, so no giant gather -- the
-            # right trade on the kernel path.  The empty-table placeholder
-            # keeps owner -9 so all-zero key lanes cannot hit it
-            t_owner0 = jnp.where(
-                consts["psort_owner"] >= 0, consts["psort_member"], jnp.int32(-9)
-            )
-            q_owner0 = jnp.where(is_member_node, member, jnp.int32(-1))
-            first = kops.hash_match(
-                key_hash, q_owner0, consts["psort_hash"], t_owner0, use_pallas=use_pallas
-            )
-        else:
-            # linked tape on the jnp path: member-windowed pass -- each
-            # node scans only its member's psort segment (<= M-hat rows),
-            # so per-node work tracks the *largest* member instead of the
-            # member sum.  Each document reads its segment once; runs
-            # never span members, so the minimal matching row in the
-            # segment is the run start, exactly as the kernel branch
-            # returns
-            seg_hash = consts["seg_hash"][schema_ids]  # (B, 8, Mh)
-            seg_row = consts["seg_row"][schema_ids]  # (B, Mh), _BIG past the segment
-            q = key_hash.reshape(B, N, 8)
-            hit = is_member_node.reshape(B, N)[:, :, None]
-            for lane in range(8):
-                hit = hit & (q[:, :, lane, None] == seg_hash[:, None, lane, :])
-            first_row = jnp.min(jnp.where(hit, seg_row[:, None, :], _BIG), axis=2)
-            first = jnp.where(first_row < _BIG, first_row, jnp.int32(-1)).reshape(B * N)
-        # one packed row per node: its candidate run (the table's last
-        # row, all owner -1, for nodes with no run)
-        cand = consts["cand"][jnp.where(first >= 0, first, M)]  # (BN, 4K)
-        cand_owner = cand[:, :k_cand]
-        cand_valid = cand_owner >= 0
-        cand_child = cand[:, k_cand : 2 * k_cand]
-        cand_slot = cand[:, 2 * k_cand : 3 * k_cand]
-        cand_orig = cand[:, 3 * k_cand :]
+    # -- hoisted single hash pass: find each object-member node's
+    # candidate-run start in its schema's hash-sorted property rows
+    M = consts["psort_owner"].shape[0]
+    if n_members == 1 or use_pallas:
+        # hash_match kernel over the whole table, owner = the row's
+        # member id (all zeros on a single tape): the kernel's minimal
+        # matching row within the querying document's member is its
+        # run start.  Streamed/blocked, so no giant gather -- the
+        # right trade on the kernel path.  The empty-table placeholder
+        # keeps owner -9 so all-zero key lanes cannot hit it
+        t_owner0 = jnp.where(
+            consts["psort_owner"] >= 0, consts["psort_member"], jnp.int32(-9)
+        )
+        q_owner0 = jnp.where(is_member_node, member, jnp.int32(-1))
+        first = kops.hash_match(
+            key_hash, q_owner0, consts["psort_hash"], t_owner0, use_pallas=use_pallas
+        )
+    else:
+        # linked tape on the jnp path: member-windowed pass -- each
+        # node scans only its member's psort segment (<= M-hat rows),
+        # so per-node work tracks the *largest* member instead of the
+        # member sum.  Each document reads its segment once; runs
+        # never span members, so the minimal matching row in the
+        # segment is the run start, exactly as the kernel branch
+        # returns
+        seg_hash = consts["seg_hash"][schema_ids]  # (B, 8, Mh)
+        seg_row = consts["seg_row"][schema_ids]  # (B, Mh), _BIG past the segment
+        q = key_hash.reshape(B, N, 8)
+        hit = is_member_node.reshape(B, N)[:, :, None]
+        for lane in range(8):
+            hit = hit & (q[:, :, lane, None] == seg_hash[:, None, lane, :])
+        first_row = jnp.min(jnp.where(hit, seg_row[:, None, :], _BIG), axis=2)
+        first = jnp.where(first_row < _BIG, first_row, jnp.int32(-1)).reshape(B * N)
+    # one packed row per node: its candidate run (the table's last
+    # row, all owner -1, for nodes with no run)
+    cand = consts["cand"][jnp.where(first >= 0, first, M)]  # (BN, 4K)
+    cand_owner = cand[:, :k_cand]
+    cand_valid = cand_owner >= 0
+    cand_child = cand[:, k_cand : 2 * k_cand]
+    cand_slot = cand[:, 2 * k_cand : 3 * k_cand]
+    cand_orig = cand[:, 3 * k_cand :]
 
     # the required-bit contribution of every node is known the moment its
     # own depth iteration resolves it -- accumulate elementwise in the
@@ -772,30 +707,17 @@ def _propagate_locations(
 
         # -- object members: property-table match
         is_member = at_depth & is_member_node
-        if layout == "csr":
-            # owner-equality over the K pre-gathered candidates; ties
-            # break to the minimal original row (dense-path semantics).
-            # Original rows are distinct, so the pick is one slot: read
-            # it by an elementwise select, not a gather per node
-            m = cand_valid & (cand_owner == parent_loc[:, None])
-            best = jnp.min(jnp.where(m, cand_orig, _BIG), axis=1)
-            matched = best < _BIG
-            pick = m & (cand_orig == best[:, None])
-            child_loc_m = jnp.sum(jnp.where(pick, cand_child, 0), axis=1)
-            slot_m = jnp.sum(jnp.where(pick, cand_slot, 0), axis=1)
-        else:
-            q_owner = jnp.where(is_member & (parent_loc >= 0), parent_loc, jnp.int32(-1))
-            row = kops.hash_match(
-                key_hash,
-                q_owner,
-                consts["prop_hash"],
-                consts["prop_owner"],
-                use_pallas=use_pallas,
-            )
-            matched = row >= 0
-            safe_row = jnp.where(matched, row, 0)
-            child_loc_m = consts["prop_child_loc"][safe_row]
-            slot_m = consts["prop_required_slot"][safe_row]
+        # owner-equality over the K pre-gathered candidates; ties break
+        # to the minimal original row, the first row the tape emitted
+        # for the key, so the pick never depends on the hash sort's
+        # order.  Original rows are distinct, so the pick is one slot:
+        # read it by an elementwise select, not a gather per node
+        m = cand_valid & (cand_owner == parent_loc[:, None])
+        best = jnp.min(jnp.where(m, cand_orig, _BIG), axis=1)
+        matched = best < _BIG
+        pick = m & (cand_orig == best[:, None])
+        child_loc_m = jnp.sum(jnp.where(pick, cand_child, 0), axis=1)
+        slot_m = jnp.sum(jnp.where(pick, cand_slot, 0), axis=1)
         child_loc = jnp.where(matched, child_loc_m, jnp.int32(LOC_UNTRACKED))
         # one packed row gather for the parent's structural facts
         p_loc_safe = jnp.where(parent_loc >= 0, parent_loc, 0)
@@ -986,22 +908,24 @@ def _anchor_gather(node_at, mat, ranks, cols, B: int, N: int):
     return jnp.where(rows >= 0, vals, True)
 
 
-def _leaf_values(node_at, circuits, B: int, N: int, *, and_mat, group_mat, and_cols, group_cols):
+def _leaf_values(node_at, circuits, B: int, N: int, *, passes, seg_any):
     """Per-document circuit-leaf values via anchored gathers.
 
-    ``and_mat``/``group_mat`` are (B*N, cols) value matrices; each leaf
-    unit reads one static column (``and_cols``/``group_cols``, per
-    layout: window slot or row id / group verdict) at its owner
-    location's anchor node.  Returns {circuit id: [(B,) bool, ...]}.
+    ``passes``/``seg_any`` are the (B*N, W) window pass matrix and
+    segmented group OR; each leaf unit reads its static window slot (an
+    AND row's own slot, a group's start slot) at its owner location's
+    anchor node.  Returns {circuit id: [(B,) bool, ...]}.
     """
     and_units, group_units = circuits["and_units"], circuits["group_units"]
     out = {}
     if and_units:
-        v = _anchor_gather(node_at, and_mat, circuits["and_ranks"], and_cols, B, N)
+        slots = [u[2] for u in and_units]
+        v = _anchor_gather(node_at, passes, circuits["and_ranks"], slots, B, N)
         for u, unit in enumerate(and_units):
             out.setdefault(unit[0], []).append(v[:, u])
     if group_units:
-        v = _anchor_gather(node_at, group_mat, circuits["group_ranks"], group_cols, B, N)
+        slots = [u[2] for u in group_units]
+        v = _anchor_gather(node_at, seg_any, circuits["group_ranks"], slots, B, N)
         for u, unit in enumerate(group_units):
             out.setdefault(unit[0], []).append(v[:, u])
     return out
@@ -1083,10 +1007,8 @@ def _validate_batch(
     max_depth: int,
     max_loc_depth: int,
     use_pallas: bool,
-    layout: str,
     n_window: int,
     k_cand: int,
-    m_hat: int,
     n_members: int,
     has_frontier: bool = False,
     circuits=None,
@@ -1094,20 +1016,16 @@ def _validate_batch(
 ):
     # the tape caps trackable depth at compile time: below
     # max_loc_depth + 1 every location is untracked or under an invalid
-    # ancestor, so the CSR loop stops there.  The dense layout keeps the
-    # historical full-depth loop as the benchmark baseline (verdicts are
-    # identical either way).
+    # ancestor, so the loop stops there
     tape_horizon = max_loc_depth + 1
-    loop_depth = min(max_depth, tape_horizon) if layout == "csr" else max_depth
+    loop_depth = min(max_depth, tape_horizon)
     loc, acquired, aux = _propagate_locations(
         cols,
         schema_ids,
         consts,
         loop_depth=loop_depth,
         use_pallas=use_pallas,
-        layout=layout,
         k_cand=k_cand,
-        m_hat=m_hat,
         n_members=n_members,
     )
     node_type = aux["node_type"]
@@ -1135,74 +1053,14 @@ def _validate_batch(
         "str_hash": flat(cols["str_hash"]),
         "str_prefix": flat(cols["str_prefix"]),
     }
-    leaf_args = None  # (and_mat, group_mat, and_cols, group_cols)
-    if layout == "csr":
-        asrt_ok, w_passes, w_seg_any = _assertions_csr(
-            loc,
-            node_cols,
-            consts,
-            use_pallas=use_pallas,
-            n_window=n_window,
-            n_circuits=n_circuits,
-        )
-        if n_circuits:
-            leaf_args = (
-                w_passes,
-                w_seg_any,
-                [u[3] for u in circuits["and_units"]],
-                [u[3] for u in circuits["group_units"]],
-            )
-    else:
-        asrt_cols = {
-            "op": consts["asrt_op"],
-            "f0": consts["asrt_f0"],
-            "i0": consts["asrt_i0"],
-            "i1": consts["asrt_i1"],
-            "u0": consts["asrt_u0"],
-            "u1": consts["asrt_u1"],
-            "hash": consts["asrt_hash"],
-        }
-        passes = kops.assertion_eval(
-            node_cols, asrt_cols, use_pallas=use_pallas
-        ).astype(bool)  # (B*N, A)
-        applies = loc[:, None] == consts["asrt_owner"][None, :]  # (B*N, A)
-
-        in_circ_row = consts["asrt_circ"] >= 0  # (A,)
-        is_and_row = (consts["asrt_group"] == 0) & ~in_circ_row
-        and_ok = jnp.all(jnp.where(applies & is_and_row[None, :], passes, True), axis=1)
-
-        # enum OR-groups: group passes iff it does not apply or any row matches
-        groups = consts["asrt_group"]
-        n_groups = int(np.asarray(groups).max()) + 1
-        group_circ = circuits["group_circ"] if n_circuits else None
-        if n_groups > 1:
-            onehot = (
-                groups[None, :, None]
-                == jnp.arange(1, n_groups, dtype=jnp.int32)[None, None, :]
-            )  # (1, A, G-1)
-            gm = jnp.any((applies & passes)[:, :, None] & onehot, axis=1)  # (B*N, G-1)
-            ga = jnp.any(applies[:, :, None] & onehot, axis=1)
-            gval = jnp.logical_or(~ga, gm)  # (B*N, G-1) per-node group verdict
-            if n_circuits:
-                plain_g = jnp.asarray(group_circ[1:] < 0)[None, :]
-                or_ok = jnp.all(gval | ~plain_g, axis=1)
-            else:
-                or_ok = jnp.all(gval, axis=1)
-        else:
-            or_ok = jnp.ones(B * N, bool)
-        asrt_ok = and_ok & or_ok
-
-        if n_circuits:
-            # circuit-leaf sources, bit-identical to the CSR path: AND
-            # leaf rows read their applied pass (the anchor node IS the
-            # applying node), enum leaf groups their per-node group
-            # verdict
-            leaf_args = (
-                passes,
-                gval if n_groups > 1 else jnp.ones((B * N, 1), bool),
-                [u[2] for u in circuits["and_units"]],
-                [u[2] - 1 for u in circuits["group_units"]],
-            )
+    asrt_ok, w_passes, w_seg_any = _assertions_csr(
+        loc,
+        node_cols,
+        consts,
+        use_pallas=use_pallas,
+        n_window=n_window,
+        n_circuits=n_circuits,
+    )
 
     # ---- 4. reduce -----------------------------------------------------------
     node_valid = ((loc != LOC_INVALID) & asrt_ok & required_ok) | is_pad
@@ -1212,16 +1070,13 @@ def _validate_batch(
     # bounded-depth reduce -> AND of gated root values into the verdict
     if n_circuits:
         node_at = _circuit_anchors(loc, circuits, B, N)
-        and_mat, group_mat, and_cols, group_cols = leaf_args
         leaf_vals = _leaf_values(
             node_at,
             circuits,
             B,
             N,
-            and_mat=and_mat,
-            group_mat=group_mat,
-            and_cols=and_cols,
-            group_cols=group_cols,
+            passes=w_passes,
+            seg_any=w_seg_any,
         )
         present = _circuit_presence(node_at, circuits)
         valid = valid & _reduce_circuits(
@@ -1276,7 +1131,6 @@ def _explain_batch(
     use_pallas: bool,
     n_window: int,
     k_cand: int,
-    m_hat: int,
     n_members: int,
     circuits=None,
     n_circuits: int = 0,
@@ -1306,9 +1160,7 @@ def _explain_batch(
         consts,
         loop_depth=loop_depth,
         use_pallas=use_pallas,
-        layout="csr",
         k_cand=k_cand,
-        m_hat=m_hat,
         n_members=n_members,
     )
     node_type = aux["node_type"]
@@ -1378,10 +1230,8 @@ def _explain_batch(
             circuits,
             B,
             N,
-            and_mat=w_passes,
-            group_mat=w_seg_any,
-            and_cols=[u[3] for u in circuits["and_units"]],
-            group_cols=[u[3] for u in circuits["group_units"]],
+            passes=w_passes,
+            seg_any=w_seg_any,
         )
         present = _circuit_presence(node_at, circuits)
         roots_out: List[Any] = []

@@ -219,7 +219,7 @@ def _eval_group(instructions: Instructions, value: Any, ctx: EvalContext) -> boo
             for inst in instructions:
                 budget.tick()
                 if not _eval_one(inst, value, ctx):
-                    if ctx.trace is not None and inst.schema_path:
+                    if ctx.trace is not None and inst.schema_path is not None:
                         ctx.trace.append((inst.schema_path, type(inst).__name__))
                     return False
             return True
@@ -227,7 +227,7 @@ def _eval_group(instructions: Instructions, value: Any, ctx: EvalContext) -> boo
             budget.exit_group()
     for inst in instructions:
         if not _eval_one(inst, value, ctx):
-            if ctx.trace is not None and inst.schema_path:
+            if ctx.trace is not None and inst.schema_path is not None:
                 ctx.trace.append((inst.schema_path, type(inst).__name__))
             return False
     return True

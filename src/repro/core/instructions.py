@@ -103,7 +103,9 @@ JSON_TYPES = ("null", "boolean", "object", "array", "number", "string", "integer
 @dataclass(frozen=True, slots=True)
 class Instruction:
     rel_path: InstancePath = ()
-    schema_path: str = ""
+    # None on instructions the compiler synthesizes with no keyword of
+    # their own; "" is the root schema itself
+    schema_path: Optional[str] = None
 
     op: OpCode = field(default=OpCode.FAIL, init=False, repr=False)
 

@@ -2,8 +2,9 @@
 
 Single source of truth for the integer codes shared by the token-table
 encoder (``data.doc_table``), the batched executor
-(``core.batch_executor``), the tape builder (``core.tape``) and both
-assertion kernels (``kernels.assertion_eval`` / ``kernels.ref``).  These
+(``core.batch_executor``), the tape builder (``core.tape``) and the
+assertion kernel and its reference (``kernels.assertion_eval`` /
+``kernels.ref``).  These
 used to be mirrored as private constants in each module; keeping them here
 means the codes cannot drift.
 

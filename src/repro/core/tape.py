@@ -3,8 +3,9 @@
 The sequential executor walks instructions per document.  The batched
 executor instead assigns every document node a **schema location id** by
 propagating locations down the BFS-ordered token table (property matching =
-the ``hash_match`` kernel), then evaluates a flat table of per-location
-assertion rows over all nodes at once (the ``assertion_eval`` kernel).
+the ``hash_match`` kernel), then evaluates each node's window of its own
+location's assertion rows, all nodes at once (the windowed
+``assertion_eval`` kernel).
 
 The tape supports the *structural subset* of the DSL that dominates API
 payload validation: types, numeric/string/array/object bounds, specialized
@@ -214,7 +215,7 @@ class LocationTape:
     ``[loc_asrt_start[l], loc_asrt_start[l] + loc_asrt_len[l])``, with the
     AND rows (group 0) first and each enum OR-group contiguous after them.
     ``max_rows_per_loc`` (compile-time constant, "A-hat") bounds every
-    window, so the batched executor can evaluate a dense (nodes x A-hat)
+    window, so the batched executor evaluates a (nodes x A-hat) window
     gather instead of the full (nodes x A) matrix.
 
     The property-transition table additionally carries a **hash-sorted
@@ -396,7 +397,7 @@ class _TapeBuilder:
         self.circ_path: List[str] = []
         self._circ_ctx: int = -1
         # source schema path of the instruction currently lowering --
-        # synthesized instructions (empty schema_path) inherit the
+        # synthesized instructions (no schema_path) inherit the
         # enclosing applicator's path (DESIGN.md §12)
         self._cur_path: str = ""
 

@@ -69,9 +69,9 @@ class AdmissionController:
     pass a shared :class:`~repro.registry.SchemaRegistry` plus
     per-record endpoint ids to :meth:`admit` for multi-tenant admission
     over the registry's linked tape -- one batched launch for the whole
-    mixed stream.  ``use_pallas``/``layout``/``max_depth`` configure the
-    batched executor when the controller owns its registry (a caller-
-    provided registry keeps its own settings).
+    mixed stream.  ``use_pallas``/``max_depth`` configure the batched
+    executor when the controller owns its registry (a caller-provided
+    registry keeps its own settings).
     """
 
     def __init__(
@@ -83,13 +83,10 @@ class AdmissionController:
         use_batch: bool = True,
         batch_max_nodes: int = 256,
         use_pallas: bool = False,
-        layout: str = "csr",
         max_depth: int = 16,
     ):
         if registry is None:
-            registry = SchemaRegistry(
-                use_pallas=use_pallas, layout=layout, max_depth=max_depth
-            )
+            registry = SchemaRegistry(use_pallas=use_pallas, max_depth=max_depth)
         self.registry = registry
         self.endpoint = endpoint
         self.use_batch = use_batch

@@ -1,23 +1,16 @@
-"""Pallas TPU kernels: fused assertion-tape evaluation (dense + windowed).
+"""Pallas TPU kernel: fused, windowed assertion-tape evaluation.
 
-Two kernels share one branch-free op evaluator (the tensorised version of
-the paper's CISC observation, §2.5 -- one *fused* pass over VMEM-resident
-columns beats dispatching many small instructions):
+One *fused* pass over VMEM-resident columns evaluates a branch-free op
+selector (the tensorised version of the paper's CISC observation, §2.5
+-- it beats dispatching many small instructions).  The executor gathers,
+per node, only the <= A-hat rows of the node's own schema location
+(owner-sorted CSR windows built at compile time in ``core.tape``) and
+hands them over as (nodes x A-hat) operand planes
+(``assertion_eval_window_pallas``).  Every op evaluates element-wise on
+(BN, W) tiles -- O(N*A-hat) -- with no ownership masking needed
+downstream (a masked slot carries op=-1 and evaluates to 0).
 
-* **Dense** (``assertion_eval_pallas``): the historical layout.  Computes
-  the full (nodes x assertion-rows) boolean matrix; ownership masking and
-  OR-group reduction happen in the surrounding jnp code.  O(N*A) compute
-  and memory -- kept as the baseline and for tapes without CSR windows.
-
-* **Windowed** (``assertion_eval_window_pallas``): the CSR fast path.  The
-  executor gathers, per node, only the <= A-hat rows of the node's own
-  schema location (owner-sorted CSR windows built at compile time in
-  ``core.tape``) and hands them over as (nodes x A-hat) operand planes.
-  Every op evaluates element-wise on (BN, W) tiles -- O(N*A-hat) instead
-  of O(N*A), with no ownership masking needed downstream (a masked slot
-  carries op=-1 and evaluates to 0).
-
-Both kernels bake in the paper's *precondition* semantics per op (wrong
+The kernel bakes in the paper's *precondition* semantics per op (wrong
 type => pass for AND rows, => no-match for OR/const rows).  float32 is
 used for numeric bounds on TPU (no native f64); the CPU reference path
 keeps f64.  Precision caveat recorded in DESIGN.md §7.
@@ -42,19 +35,18 @@ from ..core.nodetypes import (
 from ..core.tape import AOP
 
 BLOCK_N = 256
-BLOCK_A = 256
 # windowed kernel: window (A-hat) padded to a sublane multiple
 WINDOW_ALIGN = 8
 
 
 def _eval_rows(ntype, isint, num, size, acq, pfx0, pfx1, op, f0, i0, i1, u0, u1, hash_eq, out_shape):
-    """Branch-free mini-ISA evaluation shared by both kernel layouts.
+    """Branch-free mini-ISA evaluation of one tile.
 
-    Node operands are (BN, 1); assertion operands are either (1, BA)
-    (dense) or (BN, W) (windowed); ``hash_eq`` is the 8-lane string-hash
-    equality matrix already broadcast to ``out_shape``.  ``acq`` is the
-    node's acquired required-slot bitmask (the executor's location
-    propagation computes it; OBJ_HAS_SLOT reads one bit).  All candidate
+    Node operands are (BN, 1); assertion operands are (BN, W);
+    ``hash_eq`` is the 8-lane string-hash equality matrix already
+    broadcast to ``out_shape``.  ``acq`` is the node's acquired
+    required-slot bitmask (the executor's location propagation computes
+    it; OBJ_HAS_SLOT reads one bit).  All candidate
     results are computed unconditionally and combined with a select chain
     on the op code -- the VPU is wide enough that computing all candidates
     costs less than divergent control flow would.
@@ -152,133 +144,6 @@ def _eval_rows(ntype, isint, num, size, acq, pfx0, pfx1, op, f0, i0, i1, u0, u1,
         wide = jnp.broadcast_to(value.astype(jnp.int32), out_shape)
         result = jnp.where(op == code, wide, result)
     return result
-
-
-# ---------------------------------------------------------------------------
-# Dense kernel: (nodes x all-assertion-rows)
-# ---------------------------------------------------------------------------
-
-
-def _assertion_kernel(
-    # node columns, (BN, 1) each unless noted
-    n_type_ref,
-    n_isint_ref,
-    n_num_ref,
-    n_size_ref,
-    n_acq_ref,
-    n_strhash_ref,  # (BN, 8) uint32
-    n_strpfx_ref,  # (BN, 2) uint32
-    # assertion rows, (1, BA) each unless noted
-    a_op_ref,
-    a_f0_ref,
-    a_i0_ref,
-    a_i1_ref,
-    a_u0_ref,
-    a_u1_ref,
-    a_hash_ref,  # (8, BA) uint32, lane-major
-    out_ref,  # (BN, BA) int8
-):
-    ntype = n_type_ref[...]  # (BN, 1)
-    isint = n_isint_ref[...] != 0
-    num = n_num_ref[...]
-    size = n_size_ref[...]
-    acq = n_acq_ref[...]
-    pfx0 = n_strpfx_ref[:, 0:1]
-    pfx1 = n_strpfx_ref[:, 1:2]
-
-    # assertion operands arrive lane-major, so no sublane->lane relayout
-    # is needed to broadcast them against the (BN, 1) node columns
-    op = a_op_ref[...]  # (1, BA)
-    f0 = a_f0_ref[...]
-    i0 = a_i0_ref[...]
-    i1 = a_i1_ref[...]
-    u0 = a_u0_ref[...]
-    u1 = a_u1_ref[...]
-
-    # eight rank-2 lane-equality comparisons, statically unrolled
-    hash_eq = n_strhash_ref[:, 0:1] == a_hash_ref[0:1, :]
-    for lane in range(1, 8):
-        nh = n_strhash_ref[:, lane : lane + 1]  # (BN, 1)
-        ah = a_hash_ref[lane : lane + 1, :]  # (1, BA)
-        hash_eq = jnp.logical_and(hash_eq, nh == ah)
-
-    result = _eval_rows(
-        ntype, isint, num, size, acq, pfx0, pfx1, op, f0, i0, i1, u0, u1, hash_eq, out_ref.shape
-    )
-    out_ref[...] = result.astype(jnp.int8)
-
-
-def assertion_eval_pallas(
-    node_cols: dict,
-    asrt_cols: dict,
-    *,
-    block_n: int = BLOCK_N,
-    block_a: int = BLOCK_A,
-    interpret: bool = False,
-) -> jax.Array:
-    """Returns (N, A) int8 pass matrix.  Caller pads to block multiples.
-
-    node_cols: type/is_int/num/size/acquired (N,), str_hash (N,8),
-    str_prefix (N,2)
-    asrt_cols: op/f0/i0/i1/u0/u1 (A,), hash (A,8)
-    """
-    n = node_cols["type"].shape[0]
-    a = asrt_cols["op"].shape[0]
-    assert n % block_n == 0 and a % block_a == 0, (n, a)
-    grid = (n // block_n, a // block_a)
-
-    def col2d(x):
-        return x.reshape(-1, 1)
-
-    def row2d(x):
-        return x.reshape(1, -1)
-
-    n_spec = pl.BlockSpec((block_n, 1), lambda i, j: (i, 0))
-    a_spec = pl.BlockSpec((1, block_a), lambda i, j: (0, j))
-    out = pl.pallas_call(
-        _assertion_kernel,
-        grid=grid,
-        in_specs=[
-            n_spec,
-            n_spec,
-            n_spec,
-            n_spec,
-            n_spec,
-            pl.BlockSpec((block_n, 8), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 2), lambda i, j: (i, 0)),
-            a_spec,
-            a_spec,
-            a_spec,
-            a_spec,
-            a_spec,
-            a_spec,
-            pl.BlockSpec((8, block_a), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_n, block_a), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, a), jnp.int8),
-        interpret=interpret,
-    )(
-        col2d(node_cols["type"].astype(jnp.int32)),
-        col2d(node_cols["is_int"].astype(jnp.int32)),
-        col2d(node_cols["num"]),
-        col2d(node_cols["size"].astype(jnp.int32)),
-        col2d(node_cols["acquired"].astype(jnp.int32)),
-        node_cols["str_hash"],
-        node_cols["str_prefix"],
-        row2d(asrt_cols["op"].astype(jnp.int32)),
-        row2d(asrt_cols["f0"]),
-        row2d(asrt_cols["i0"].astype(jnp.int32)),
-        row2d(asrt_cols["i1"].astype(jnp.int32)),
-        row2d(asrt_cols["u0"]),
-        row2d(asrt_cols["u1"]),
-        jnp.transpose(asrt_cols["hash"]),
-    )
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Windowed kernel: (nodes x A-hat) pre-gathered CSR windows
-# ---------------------------------------------------------------------------
 
 
 def _assertion_window_kernel(

@@ -11,13 +11,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import assertion_eval as _ae
 from . import hash_match as _hm
 from . import ref as _ref
 
-__all__ = ["hash_match", "assertion_eval", "assertion_eval_window"]
+__all__ = ["hash_match", "assertion_eval_window"]
 
 
 def _interpret_default() -> bool:
@@ -41,8 +40,8 @@ def _with_acquired(node_cols: dict) -> dict:
     """Default the acquired-slot bitmask to zeros for callers without one.
 
     OBJ_HAS_SLOT rows only appear on tapes with logical-applicator
-    circuits; plain callers (kernel tests, dense baselines over
-    circuit-free tapes) need not thread the column through.
+    circuits; plain callers (kernel tests over circuit-free rows) need
+    not thread the column through.
     """
     if "acquired" in node_cols:
         return node_cols
@@ -82,37 +81,6 @@ def hash_match(
         interpret=interpret,
     )
     return out[:n]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "block_a", "use_pallas", "interpret")
-)
-def assertion_eval(
-    node_cols: dict,
-    asrt_cols: dict,
-    *,
-    block_n: int = _ae.BLOCK_N,
-    block_a: int = _ae.BLOCK_A,
-    use_pallas: bool = True,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """(N, A) int8 pass matrix (see assertion_eval.py)."""
-    node_cols = _with_acquired(node_cols)
-    if not use_pallas:
-        return _ref.assertion_eval_ref(node_cols, asrt_cols)
-    interpret = _interpret_default() if interpret is None else interpret
-    n = node_cols["type"].shape[0]
-    a = asrt_cols["op"].shape[0]
-    np_, ap = _round_up(n, block_n), _round_up(a, block_a)
-    node_pad = {k: _pad_to(v, np_) for k, v in node_cols.items()}
-    # padded assertion rows get op -1 -> never selected -> result 0
-    asrt_pad = {
-        k: _pad_to(v, ap, fill=(-1 if k == "op" else 0)) for k, v in asrt_cols.items()
-    }
-    out = _ae.assertion_eval_pallas(
-        node_pad, asrt_pad, block_n=block_n, block_a=block_a, interpret=interpret
-    )
-    return out[:n, :a]
 
 
 @functools.partial(

@@ -36,7 +36,7 @@ def _eval_rows_ref(ntype, isint, num, size, acq, str_pfx0, str_pfx1, op, f0, i0,
 
     ``hash_eq`` carries the 8-lane string-hash equality at the output
     shape; node operands are (N, 1) (``acq`` is the acquired required-slot
-    bitmask), assertion operands (1, A) or (N, W).
+    bitmask), assertion operands (N, W).
     """
     out_shape = hash_eq.shape
 
@@ -118,32 +118,6 @@ def _eval_rows_ref(ntype, isint, num, size, acq, str_pfx0, str_pfx1, op, f0, i0,
     ]:
         result = jnp.where(op == code, jnp.broadcast_to(value, out_shape), result)
     return result
-
-
-def assertion_eval_ref(node_cols: dict, asrt_cols: dict) -> jax.Array:
-    """(N, A) int8 pass matrix -- mirror of the dense Pallas kernel."""
-    ntype = node_cols["type"].astype(jnp.int32)[:, None]  # (N, 1)
-    isint = node_cols["is_int"].astype(bool)[:, None]
-    num = node_cols["num"][:, None]
-    size = node_cols["size"].astype(jnp.int32)[:, None]
-    acq = node_cols["acquired"].astype(jnp.int32)[:, None]
-    str_hash = node_cols["str_hash"]  # (N, 8)
-    str_pfx = node_cols["str_prefix"]  # (N, 2)
-
-    op = asrt_cols["op"].astype(jnp.int32)[None, :]  # (1, A)
-    f0 = asrt_cols["f0"][None, :]
-    i0 = asrt_cols["i0"].astype(jnp.int32)[None, :]
-    i1 = asrt_cols["i1"].astype(jnp.int32)[None, :]
-    u0 = asrt_cols["u0"][None, :]
-    u1 = asrt_cols["u1"][None, :]
-    a_hash = asrt_cols["hash"]  # (A, 8)
-
-    hash_eq = jnp.all(str_hash[:, None, :] == a_hash[None, :, :], axis=-1)  # (N, A)
-    result = _eval_rows_ref(
-        ntype, isint, num, size, acq, str_pfx[:, 0:1], str_pfx[:, 1:2],
-        op, f0, i0, i1, u0, u1, hash_eq,
-    )
-    return result.astype(jnp.int8)
 
 
 def assertion_eval_window_ref(node_cols: dict, w_cols: dict) -> jax.Array:

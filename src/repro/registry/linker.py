@@ -23,7 +23,8 @@ Relocation scheme (DESIGN.md §8):
   CSR windows stay contiguous: ``loc_asrt_start`` shifts by the member's
   row offset, ``loc_asrt_len`` is untouched.
 - **enum OR-group ids** shift by the running maximum so they stay
-  globally unique (the dense layout reduces groups globally).
+  globally unique (the executor's circuit-leaf wiring keys groups by
+  id across the whole tape).
 - **circuit nodes** (logical applicators, DESIGN.md §10) concatenate in
   member order: ``circ_parent`` and ``asrt_circ`` shift by the member's
   circuit offset (-1 sentinels preserved), ``circ_owner`` by its

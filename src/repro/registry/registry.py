@@ -213,7 +213,6 @@ class SchemaRegistry:
         *,
         engine: str = "codegen",
         use_pallas: bool = False,
-        layout: str = "csr",
         max_depth: int = 16,
         unroll_depth: Optional[int] = None,
         guard: GuardLimits = GuardLimits(),
@@ -228,7 +227,6 @@ class SchemaRegistry:
     ):
         self.engine = engine
         self.use_pallas = use_pallas
-        self.layout = layout
         self.max_depth = max_depth
         # $ref-unroll sizing (DESIGN.md §15): None = auto -- honor the
         # REPRO_UNROLL_DEPTH env override, else size per schema from the
@@ -734,7 +732,6 @@ class SchemaRegistry:
                         tape,
                         max_depth=self.max_depth,
                         use_pallas=self.use_pallas,
-                        layout=self.layout,
                         metrics=self.metrics,
                     )
                 g = LinkGroup(
@@ -884,7 +881,6 @@ class SchemaRegistry:
                     self._linked,
                     max_depth=self.max_depth,
                     use_pallas=self.use_pallas,
-                    layout=self.layout,
                     metrics=self.metrics,
                 )
             else:

@@ -2,7 +2,7 @@
 
 Hypothesis-free (the CI image may lack it): a seeded ``random``-based
 schema/document fuzzer compares the CSR executor against the sequential
-oracle and checks CSR vs dense bit-identity, plus directed cases for enum
+oracle and checks jnp vs Pallas bit-identity, plus directed cases for enum
 OR-groups, the depth>max_depth undecided flag, and the
 >32-required-properties ``UnsupportedForBatch`` fallback.
 """
@@ -89,10 +89,10 @@ def _rand_doc(rng: random.Random, depth: int):
 
 
 class TestDifferentialFuzz:
-    def test_csr_matches_sequential_and_dense(self):
+    def test_csr_matches_sequential(self):
         rng = random.Random(0xB1A2E)
         tapes = 0
-        # every distinct tape shape recompiles both executors: keep the
+        # every distinct tape shape recompiles the executor: keep the
         # trial count CI-friendly
         for trial in range(60):
             schema = _rand_schema(rng, 3)
@@ -105,13 +105,8 @@ class TestDifferentialFuzz:
             seq = Validator(compiled)
             expected = [seq.is_valid(d) for d in docs]
             table = encode_batch(docs, max_nodes=64, max_depth=8)
-            csr = BatchValidator(tape, max_depth=8, use_pallas=False, layout="csr")
-            dense = BatchValidator(tape, max_depth=8, use_pallas=False, layout="dense")
+            csr = BatchValidator(tape, max_depth=8, use_pallas=False)
             v_c, d_c = csr.validate(table)
-            v_d, d_d = dense.validate(table)
-            # bit-identical across layouts (the acceptance criterion)
-            np.testing.assert_array_equal(v_c, v_d, err_msg=repr(schema))
-            np.testing.assert_array_equal(d_c, d_d, err_msg=repr(schema))
             for i, (v, d) in enumerate(zip(v_c, d_c)):
                 if d:
                     assert bool(v) == expected[i], (schema, docs[i])

@@ -56,3 +56,22 @@ class TestExplain:
             ok, trace = v.explain(d)
             assert ok == v.is_valid(d), d
             assert ok == (trace == []) or not ok, d
+
+    def test_root_failure_names_the_root(self):
+        """A failure at the root schema itself (keyword location "") is
+        traced like any other: ``false`` rejects everything at ""."""
+        ok, trace = Validator(compile_schema(False)).explain(None)
+        assert not ok
+        assert "" in {p for p, _ in trace}, trace
+
+    def test_synthesized_condition_rows_stay_untraced(self):
+        """Rows the compiler synthesizes for a condition carry no keyword
+        location and never enter the trace, though they fail here (the
+        document lacks "a")."""
+        from repro.core.compiler import CompilerOptions
+
+        schema = {"dependentSchemas": {"a": {"required": ["b"]}}, "not": {}}
+        options = CompilerOptions(cisc=False, reorder=False)
+        ok, trace = Validator(compile_schema(schema, options=options)).explain({"x": 1})
+        assert not ok
+        assert trace == [("/not", "AssertionFail")], trace

@@ -99,7 +99,7 @@ class TestHashMatch:
 
 
 # ---------------------------------------------------------------------------
-# assertion_eval
+# assertion_eval_window
 # ---------------------------------------------------------------------------
 
 
@@ -117,17 +117,34 @@ def _random_nodes(rng, n):
     }
 
 
-def _random_asrt(rng, a):
+def _random_window(rng, n, w):
+    """Per-node (n, w) window operands; about a fifth of the slots are
+    masked (op -1), as past a location's last row."""
+    op = rng.integers(0, 19, (n, w)).astype(np.int32)
+    op[rng.random((n, w)) < 0.2] = -1
     return {
-        "op": jnp.asarray(rng.integers(0, 19, a).astype(np.int32)),
-        "f0": jnp.asarray(rng.normal(0, 5, a).astype(np.float32)),
-        "i0": jnp.asarray(rng.integers(0, 0xFF, a).astype(np.int32)),
-        "i1": jnp.asarray(rng.integers(0, 2, a).astype(np.int32)),
-        "u0": jnp.asarray(rng.integers(0, 2**32, a, dtype=np.uint64).astype(np.uint32)),
-        "u1": jnp.asarray(rng.integers(0, 2**32, a, dtype=np.uint64).astype(np.uint32)),
+        "op": jnp.asarray(op),
+        "f0": jnp.asarray(rng.normal(0, 5, (n, w)).astype(np.float32)),
+        "i0": jnp.asarray(rng.integers(0, 0xFF, (n, w)).astype(np.int32)),
+        "i1": jnp.asarray(rng.integers(0, 2, (n, w)).astype(np.int32)),
+        "u0": jnp.asarray(rng.integers(0, 2**32, (n, w), dtype=np.uint64).astype(np.uint32)),
+        "u1": jnp.asarray(rng.integers(0, 2**32, (n, w), dtype=np.uint64).astype(np.uint32)),
         "hash": jnp.asarray(
-            np.stack([_POOL[i] for i in rng.integers(0, len(_POOL), a)])
+            np.stack([_POOL[i] for i in rng.integers(0, len(_POOL), n * w)]).reshape(n, w, 8)
         ),
+    }
+
+
+def _one_slot(op, *, f0=0.0, i0=0, u0=0, u1=0):
+    """A one-node window: slot 0 holds the row, slot 1 is masked."""
+    return {
+        "op": jnp.asarray([[op, -1]], jnp.int32),
+        "f0": jnp.asarray([[f0, 0.0]], jnp.float32),
+        "i0": jnp.asarray([[i0, 0]], jnp.int32),
+        "i1": jnp.zeros((1, 2), jnp.int32),
+        "u0": jnp.asarray([[u0, 0]], jnp.uint32),
+        "u1": jnp.asarray([[u1, 0]], jnp.uint32),
+        "hash": jnp.zeros((1, 2, 8), jnp.uint32),
     }
 
 
@@ -135,22 +152,23 @@ class TestAssertionEval:
     @pytest.mark.parametrize("n,a", [(1, 1), (5, 17), (128, 128), (200, 70), (257, 129)])
     def test_shapes_match_ref(self, n, a):
         rng = np.random.default_rng(n * 31 + a)
-        nodes, asrts = _random_nodes(rng, n), _random_asrt(rng, a)
-        got = kops.assertion_eval(nodes, asrts, block_n=128, block_a=128)
-        want = kref.assertion_eval_ref(nodes, asrts)
+        nodes, window = _random_nodes(rng, n), _random_window(rng, n, a)
+        got = kops.assertion_eval_window(nodes, window, block_n=128)
+        want = kref.assertion_eval_window_ref(nodes, window)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     @settings(max_examples=25, deadline=None)
     @given(n=st.integers(1, 30), a=st.integers(1, 30), seed=st.integers(0, 2**16))
     def test_property_sweep(self, n, a, seed):
         rng = np.random.default_rng(seed)
-        nodes, asrts = _random_nodes(rng, n), _random_asrt(rng, a)
-        got = kops.assertion_eval(nodes, asrts, block_n=8, block_a=8)
-        want = kref.assertion_eval_ref(nodes, asrts)
+        nodes, window = _random_nodes(rng, n), _random_window(rng, n, a)
+        got = kops.assertion_eval_window(nodes, window, block_n=8)
+        want = kref.assertion_eval_window_ref(nodes, window)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_precondition_semantics(self):
-        """Wrong-typed nodes pass AND rows (paper §5.2)."""
+        """Wrong-typed nodes pass AND rows (paper §5.2); masked slots
+        evaluate to 0."""
         nodes = {
             "type": jnp.asarray([4], jnp.int32),  # string
             "is_int": jnp.zeros(1, jnp.int32),
@@ -159,16 +177,10 @@ class TestAssertionEval:
             "str_hash": jnp.zeros((1, 8), jnp.uint32),
             "str_prefix": jnp.zeros((1, 2), jnp.uint32),
         }
-        asrts = {
-            "op": jnp.asarray([AOP.NUM_GE], jnp.int32),
-            "f0": jnp.asarray([100.0], jnp.float32),
-            "i0": jnp.zeros(1, jnp.int32),
-            "i1": jnp.zeros(1, jnp.int32),
-            "u0": jnp.zeros(1, jnp.uint32),
-            "u1": jnp.zeros(1, jnp.uint32),
-            "hash": jnp.zeros((1, 8), jnp.uint32),
-        }
-        assert int(kops.assertion_eval(nodes, asrts)[0, 0]) == 1
+        window = _one_slot(AOP.NUM_GE, f0=100.0)
+        for use_pallas in (True, False):
+            got = kops.assertion_eval_window(nodes, window, use_pallas=use_pallas)
+            assert np.asarray(got).tolist() == [[1, 0]], use_pallas
 
     def test_str_prefix_check(self):
         from repro.data.doc_table import _text_tables
@@ -183,13 +195,12 @@ class TestAssertionEval:
             "str_prefix": jnp.asarray([[p0, p1]], jnp.uint32),
         }
         pfx = b"x-".ljust(8, b"\x00")
-        asrts = {
-            "op": jnp.asarray([AOP.STR_PREFIX], jnp.int32),
-            "f0": jnp.zeros(1, jnp.float32),
-            "i0": jnp.asarray([2], jnp.int32),
-            "i1": jnp.zeros(1, jnp.int32),
-            "u0": jnp.asarray([int.from_bytes(pfx[:4], "big")], jnp.uint32),
-            "u1": jnp.asarray([int.from_bytes(pfx[4:], "big")], jnp.uint32),
-            "hash": jnp.zeros((1, 8), jnp.uint32),
-        }
-        assert int(kops.assertion_eval(nodes, asrts)[0, 0]) == 1
+        window = _one_slot(
+            AOP.STR_PREFIX,
+            i0=2,
+            u0=int.from_bytes(pfx[:4], "big"),
+            u1=int.from_bytes(pfx[4:], "big"),
+        )
+        for use_pallas in (True, False):
+            got = kops.assertion_eval_window(nodes, window, use_pallas=use_pallas)
+            assert np.asarray(got).tolist() == [[1, 0]], use_pallas

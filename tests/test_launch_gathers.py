@@ -221,11 +221,6 @@ def test_packed_launch_verdicts_and_sites(name, use_pallas):
     if name == "frontier":
         assert frontier.any() and not decided[frontier].any()
 
-    # the dense layout reads the unpacked columns: bit-identical verdicts
-    dv, dd = BatchValidator(tape, max_depth=8, use_pallas=False, layout="dense").validate(table)
-    np.testing.assert_array_equal(valid, dv)
-    np.testing.assert_array_equal(decided, dd)
-
     iv, idec, ifr, errors = bv.validate_isolated(table)
     assert not errors
     np.testing.assert_array_equal(iv, valid)

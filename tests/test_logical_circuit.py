@@ -1,8 +1,8 @@
 """Batched logical applicators via assertion-group circuits (DESIGN.md §10).
 
 Differential fuzz of nested ``anyOf``/``oneOf``/``not``/``if`` schemas
-over the scalar subset against the sequential oracle, CSR==dense (and
-spot-checked pallas) bit-identity, conditional-requiredness semantics,
+over the scalar subset against the sequential oracle, jnp==Pallas
+(spot-checked) bit-identity, conditional-requiredness semantics,
 mixed-registry linking with a tagged-union member, and precise
 ``UnsupportedForBatch`` reasons for out-of-subset branches.
 """
@@ -78,26 +78,23 @@ def _check(schema, docs, *, max_nodes=64, max_depth=8, pallas=False):
     assert tape is not None, (schema, reason)
     table = encode_batch(docs, max_nodes=max_nodes, max_depth=max_depth)
     expected = [seq.is_valid(d) for d in docs]
-    layouts = [("csr", False), ("dense", False)] + ([("csr", True)] if pallas else [])
     results = {}
-    for layout, use_pallas in layouts:
-        bv = BatchValidator(
-            tape, max_depth=max_depth, use_pallas=use_pallas, layout=layout
-        )
+    for use_pallas in (False, True) if pallas else (False,):
+        bv = BatchValidator(tape, max_depth=max_depth, use_pallas=use_pallas)
         v, d = bv.validate(table)
-        results[(layout, use_pallas)] = (v, d)
+        results[use_pallas] = (v, d)
         for i, doc in enumerate(docs):
             if d[i]:
-                assert bool(v[i]) == expected[i], (layout, use_pallas, schema, doc)
-    base_v, base_d = results[("csr", False)]
+                assert bool(v[i]) == expected[i], (use_pallas, schema, doc)
+    base_v, base_d = results[False]
     for key, (v, d) in results.items():
         np.testing.assert_array_equal(v, base_v, err_msg=repr((key, schema)))
         np.testing.assert_array_equal(d, base_d, err_msg=repr((key, schema)))
-    return tape, results[("csr", False)]
+    return tape, results[False]
 
 
 class TestDirectedCircuits:
-    def test_discriminated_union_all_layouts_and_pallas(self):
+    def test_discriminated_union_jnp_and_pallas(self):
         tape, (v, d) = _check(UNION, UNION_DOCS, pallas=True)
         assert tape.n_circuits >= 4  # XOR1 + three branch ANDs
         assert d.all()
@@ -370,7 +367,7 @@ def _rand_logical(rng: random.Random, depth: int) -> dict:
 
 
 class TestDifferentialFuzz:
-    def test_circuits_match_sequential_and_dense(self):
+    def test_circuits_match_sequential(self):
         rng = random.Random(0x10C1C)
         tapes = circuits = checked_sites = 0
         for trial in range(80):
@@ -385,12 +382,8 @@ class TestDifferentialFuzz:
             seq = Validator(compiled)
             expected = [seq.is_valid(d) for d in docs]
             table = encode_batch(docs, max_nodes=64, max_depth=8)
-            csr = BatchValidator(tape, max_depth=8, use_pallas=False, layout="csr")
-            dense = BatchValidator(tape, max_depth=8, use_pallas=False, layout="dense")
+            csr = BatchValidator(tape, max_depth=8, use_pallas=False)
             v_c, d_c = csr.validate(table)
-            v_d, d_d = dense.validate(table)
-            np.testing.assert_array_equal(v_c, v_d, err_msg=repr(schema))
-            np.testing.assert_array_equal(d_c, d_d, err_msg=repr(schema))
             for i, (v, d) in enumerate(zip(v_c, d_c)):
                 if d:
                     assert bool(v) == expected[i], (schema, docs[i])

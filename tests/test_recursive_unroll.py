@@ -6,7 +6,7 @@ location tapes: ``ControlLabel``/``ControlJump`` cycles unroll up to the
 ``LOC_FRONTIER`` sentinel.  The contract under test:
 
 * documents shallower than the budget are **decided** on the batched
-  path and bit-identical to the sequential oracle (CSR == dense too);
+  path and bit-identical to the sequential oracle (jnp == Pallas too);
 * documents that reach a frontier are **undecided** -- never vacuously
   valid -- and ``validate_ex`` flags them so callers can count
   ``unroll_overflow`` fallbacks distinctly;
@@ -121,19 +121,18 @@ class TestLinkedListUnroll:
             # frontier-reaching docs are undecided, never vacuously valid
             assert bool(frontier[i]) == (d > 4), (i, d)
 
-    def test_csr_dense_pallas_bit_identity(self):
-        _, tape = self._build()
+    def test_csr_pallas_bit_identity(self):
+        compiled, tape = self._build()
+        seq = Validator(compiled)
         docs = [chain(d) for d in range(7)]
         table = encode_batch(docs, max_nodes=64)
-        ref = BatchValidator(tape, use_pallas=False, layout="csr")
-        v0, d0 = ref.validate(table)
-        for kwargs in (
-            dict(use_pallas=False, layout="dense"),
-            dict(use_pallas=True, layout="csr"),
-        ):
-            v, d = BatchValidator(tape, **kwargs).validate(table)
-            np.testing.assert_array_equal(v, v0, err_msg=repr(kwargs))
-            np.testing.assert_array_equal(d, d0, err_msg=repr(kwargs))
+        v0, d0 = BatchValidator(tape, use_pallas=False).validate(table)
+        for i, doc in enumerate(docs):
+            if d0[i]:
+                assert bool(v0[i]) == seq.is_valid(doc), doc
+        v, d = BatchValidator(tape, use_pallas=True).validate(table)
+        np.testing.assert_array_equal(v, v0)
+        np.testing.assert_array_equal(d, d0)
 
     def test_unroll_depth_one(self):
         compiled, tape = self._build(unroll_depth=1)
@@ -382,13 +381,7 @@ class TestRecursiveDifferentialFuzz:
             ]
             table = encode_batch(docs, max_nodes=256)
             csr = BatchValidator(tape, max_depth=16, use_pallas=False)
-            dense = BatchValidator(
-                tape, max_depth=16, use_pallas=False, layout="dense"
-            )
             v, d, f = csr.validate_ex(table)
-            v2, d2 = dense.validate(table)
-            np.testing.assert_array_equal(v, v2, err_msg=repr(schema))
-            np.testing.assert_array_equal(d, d2, err_msg=repr(schema))
             # frontier-reaching docs are exactly the undecided ones here
             # (depths fit both encoder and executor budgets)
             np.testing.assert_array_equal(f, ~d, err_msg=repr(schema))

@@ -199,12 +199,11 @@ class TestMixedBatchDifferential:
         ids = np.array([0, 0, 0, 1, 1, 1, 2, 2, 2, 2], np.int32)
         table = encode_batch(docs, max_nodes=32)
         seqs = [Validator(compile_schema(s)) for s in SCHEMAS]
-        for layout in ("csr", "dense"):
-            bv = BatchValidator(linked, use_pallas=False, layout=layout)
-            valid, decided = bv.validate(table, ids)
-            assert decided.all()
-            for i, d in enumerate(docs):
-                assert bool(valid[i]) == seqs[ids[i]].is_valid(d), (layout, d)
+        bv = BatchValidator(linked, use_pallas=False)
+        valid, decided = bv.validate(table, ids)
+        assert decided.all()
+        for i, d in enumerate(docs):
+            assert bool(valid[i]) == seqs[ids[i]].is_valid(d), d
 
     def test_fuzz_mixed_vs_sequential_and_per_schema(self):
         rng = random.Random(0x11C8)
@@ -462,12 +461,16 @@ class TestMultiTenantAdmission:
         assert ctrl.stats.fallback_validated == 1
         assert ctrl.stats.batch_validated == 1
 
-    def test_use_pallas_and_layout_kwargs_exposed(self):
-        ctrl = AdmissionController(S1, use_pallas=False, layout="dense")
-        assert ctrl.registry.layout == "dense"
+    def test_use_pallas_kwarg_exposed(self):
+        ctrl = AdmissionController(S1, use_pallas=False)
+        assert ctrl.registry.use_pallas is False
         assert ctrl.batch_validator is not None
-        assert ctrl.batch_validator.layout == "dense"
         assert ctrl.batch_validator.use_pallas is False
+        # one batched layout: no layout option at any level
+        with pytest.raises(TypeError):
+            AdmissionController(S1, layout="csr")
+        with pytest.raises(TypeError):
+            BatchValidator(ctrl.batch_validator.tape, layout="csr")
         oks = ctrl.admit([{"name": "x"}, {"name": ""}])
         assert oks == [True, False]
 

@@ -3,10 +3,10 @@
 The TPU compiler ships with the installed JAX and compiles for a chip
 that is described rather than attached.  Interpret-mode tests cannot see
 what Mosaic refuses (unsupported casts, scoped-VMEM overflows), so each
-Pallas kernel of the gateway path, and the jitted launch that calls them,
-is compiled here at the shape of one B=4096 x 64-node gateway batch:
-N = 262144 nodes, a window of W = 8 assertion rows per node, and M = A =
-256 property-table and assertion rows.  A compile that passes is not a
+Pallas kernel of the gateway path, and the jitted launch and explain
+programs that call them, are compiled here at the shape of one B=4096 x
+64-node gateway batch: N = 262144 nodes, a window of W = 8 assertion rows
+per node, and M = 256 property-table rows.  A compile that passes is not a
 chip run; it only says the chip's compiler accepts the program.
 
 This is the only file that describes the chip.  The topology is built
@@ -114,14 +114,8 @@ def _assertion_eval_window(sds):
     )
 
 
-def _assertion_eval(sds):
-    from repro.kernels.assertion_eval import assertion_eval_pallas
-
-    return assertion_eval_pallas, (_node_cols(sds), _row_cols(sds, (N_ROWS,)))
-
-
 @pytest.mark.parametrize(
-    "kernel", [_hash_match, _assertion_eval_window, _assertion_eval], ids=lambda k: k.__name__[1:]
+    "kernel", [_hash_match, _assertion_eval_window], ids=lambda k: k.__name__[1:]
 )
 def test_kernel_compiles_for_v5e(kernel, on_chip):
     fn, args = kernel(on_chip)
@@ -142,7 +136,9 @@ def mosaic_kernels(monkeypatch):
     jax.clear_caches()
 
 
-def test_gateway_launch_compiles_for_v5e(on_chip, mosaic_kernels):
+def _gateway_program(sds, program):
+    """One of the gateway validator's jitted programs, lowered at one
+    B=4096 x 64-node batch."""
     from repro.data.doc_table import encode_batch
     from repro.registry import SchemaRegistry
     from repro.registry.presets import GATEWAY_SCHEMAS
@@ -155,9 +151,19 @@ def test_gateway_launch_compiles_for_v5e(on_chip, mosaic_kernels):
     batch = N_NODES // 64
     columns = encode_batch([None], max_nodes=64).columns()
     cols = {
-        k: on_chip((batch,) + v.shape[1:], jax.dtypes.canonicalize_dtype(v.dtype))
+        k: sds((batch,) + v.shape[1:], jax.dtypes.canonicalize_dtype(v.dtype))
         for k, v in columns.items()
     }
-    compiled = validator._fn.lower(cols, on_chip((batch,), jnp.int32)).compile()
+    return getattr(validator, program).lower(cols, sds((batch,), jnp.int32))
+
+
+def test_gateway_launch_compiles_for_v5e(on_chip, mosaic_kernels):
+    compiled = _gateway_program(on_chip, "_fn").compile()
+    # hash_match and the windowed assertion kernel
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_explain_launch_compiles_for_v5e(on_chip, mosaic_kernels):
+    compiled = _gateway_program(on_chip, "_explain_fn").compile()
     # hash_match and the windowed assertion kernel
     assert compiled.as_text().count("tpu_custom_call") >= 2
