@@ -158,7 +158,7 @@ def run(report: Dict[str, object]) -> List[str]:
             seq_results = [seq.is_valid(p, parsed=True) for p in parsed]
             t_seq = time.perf_counter() - t0
             t0 = time.perf_counter()
-            [seq_cg.is_valid(p, parsed=True) for p in parsed]
+            [seq_cg.is_valid(d) for d in docs]  # codegen reads the decoded tree
             t_seq_cg = time.perf_counter() - t0
             t0 = time.perf_counter()
             table = encode_batch(docs, max_nodes=max_nodes)

@@ -29,7 +29,6 @@ from typing import Dict, List
 import numpy as np
 
 from repro.core.batch_executor import BatchValidator
-from repro.core.doc_model import parse_document
 from repro.data.doc_table import encode_batch
 from repro.registry import SchemaRegistry
 from repro.registry.presets import GATEWAY_SCHEMAS as SCHEMAS
@@ -123,17 +122,12 @@ def run(report: Dict[str, object]) -> List[str]:
         assert (ids >= 0).all()
 
         # -- sequential oracle ------------------------------------------------
-        parsed = [parse_document(d) for d in docs]
+        # the registry's codegen validators read the decoded tree as it is
         validators = {ep: reg.get(ep).validator for ep in SCHEMAS}
-        seq_results = [
-            validators[ep].is_valid(p, parsed=True) for ep, p in zip(endpoints, parsed)
-        ]
+        seq_results = [validators[ep].is_valid(d) for ep, d in zip(endpoints, docs)]
 
         def run_seq():
-            return [
-                validators[ep].is_valid(p, parsed=True)
-                for ep, p in zip(endpoints, parsed)
-            ]
+            return [validators[ep].is_valid(d) for ep, d in zip(endpoints, docs)]
 
         # -- per-schema sub-batch dispatch -----------------------------------
         # Two baselines: *exact* warms a jit for each group's exact batch

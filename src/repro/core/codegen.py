@@ -2,17 +2,28 @@
 
 The paper's §8 names "precompiling the code necessary to validate each
 schema" as future work; this module does it.  Each instruction compiles to
-a specialized Python closure with every operand, hash, and type test
+a specialized Python closure with every operand, key, and type test
 pre-bound -- eliminating opcode dispatch, dataclass attribute loads, and
 precondition re-derivation from the per-document hot path.  Semantics are
 identical to executor.py (differentially tested in tests/test_codegen.py).
 
+Document model: the closures read the decoded JSON tree as it is -- a
+``dict`` is an object, a ``list`` an array (subclasses included, as
+``parse_document`` takes them; a tuple is neither).  No per-document
+rebuild: ``str`` keys cache their hash, so every keyed lookup is one
+``dict`` probe -- the paper's §4.1 "hash once, at parse time", done by
+the JSON decoder.  The interpreter (executor.py) and the explain trace
+keep the paper's model, ``parse_document``'s ``HashedObject`` with its
+semi-perfect hashes, and are the oracle.
+
 Notes on specialization:
-* exact ``type(x) is`` tests (the document model produces exact types;
-  bool/int discrimination falls out for free);
+* ``type(x) is`` tests first; a container test falls back to
+  ``isinstance`` only on a miss, so subclasses cost nothing on the common
+  path (bool/int discrimination falls out for free);
 * scalar const/enum tests split by type at compile time -- enum membership
   is one frozenset probe, no json_equal walk;
-* property matching uses dicts keyed by the semi-perfect hash, built once.
+* property matching is one ``dict`` probe per entry, into a table keyed by
+  the property name and built once; excluded keys are a frozenset.
 
 Metering (DESIGN.md §11): one closure set serves both the unbounded hot
 path and the bounded fallback.  Every group closure reads the ``budget``
@@ -22,16 +33,18 @@ slot of the validator's :class:`~repro.core.executor.EvalContext`.  Left
 ``executor._eval_group`` does -- ``enter_group``, one ``tick`` before
 each instruction, ``exit_group`` -- and engine regexes pass the budget's
 ``regex_gate`` first, so step counts, depth bounds and refusals match the
-interpreter's.
+interpreter's.  With no parse, ``Validator`` holds the decoded tree to
+the parse's stack bound (``doc_model.nesting_exceeds``) before the
+closures run, so a depth bomb is refused as the interpreter refuses it;
+a nesting the schema follows stops at the budget's depth bound.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 from .compiler import CompiledSchema
-from .doc_model import HashedObject, canonical, json_equal
-from .hashing import is_short_hash
+from .doc_model import canonical, json_equal
 from .instructions import Instruction, Instructions, OpCode
 from .regex_opt import RegexKind
 
@@ -50,9 +63,9 @@ def _type_check(t: str) -> Check:
     if t == "number":
         return lambda v: type(v) is int or type(v) is float
     if t == "object":
-        return lambda v: type(v) is HashedObject
+        return lambda v: type(v) is dict or isinstance(v, dict)
     if t == "array":
-        return lambda v: type(v) is list
+        return lambda v: type(v) is list or isinstance(v, list)
     if t == "boolean":
         return lambda v: type(v) is bool
     if t == "null":
@@ -171,20 +184,16 @@ class _Codegen:
         inner = self.body(inst)
         if not inst.rel_path:
             return inner
-        # fold relative resolution into the closure; hashes precomputed here
-        from .hashing import shash
+        # fold relative resolution into the closure: str tokens are keys,
+        # int tokens array indices
+        path = tuple(inst.rel_path)
+        if len(path) == 1 and type(path[0]) is str:
+            (key,) = path
 
-        path = tuple(
-            (tok, shash(tok)) if isinstance(tok, str) else tok
-            for tok in inst.rel_path
-        )
-        if len(path) == 1 and type(path[0]) is tuple:
-            key, h = path[0]
-
-            def resolved(v, _inner=inner, _k=key, _h=h):
-                if type(v) is not HashedObject:
+            def resolved(v, _inner=inner, _k=key):
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                child = v.get_hashed(_h, _k, _MISS)
+                child = v.get(_k, _MISS)
                 if child is _MISS:
                     return True
                 return _inner(child)
@@ -194,14 +203,16 @@ class _Codegen:
         def resolved_deep(v, _inner=inner, _path=path):
             node = v
             for tok in _path:
-                if type(tok) is tuple:
-                    if type(node) is not HashedObject:
+                if type(tok) is str:
+                    if type(node) is not dict and not isinstance(node, dict):
                         return True
-                    node = node.get_hashed(tok[1], tok[0], _MISS)
+                    node = node.get(tok, _MISS)
                     if node is _MISS:
                         return True
                 else:
-                    if type(node) is not list or not 0 <= tok < len(node):
+                    if type(node) is not list and not isinstance(node, list):
+                        return True
+                    if not 0 <= tok < len(node):
                         return True
                     node = node[tok]
             return _inner(node)
@@ -223,56 +234,52 @@ class _Codegen:
             return _enum_check(inst.values)
 
         if op is OpCode.DEFINES:
-            k, h = inst.key, inst.key_hash
-            return (
-                lambda v: type(v) is not HashedObject
-                or v.get_hashed(h, k, _MISS) is not _MISS
-            )
+            k = inst.key
+            return lambda v: (type(v) is not dict and not isinstance(v, dict)) or k in v
         if op is OpCode.DEFINES_ALL:
-            pairs = tuple(zip(inst.key_hashes, inst.keys))
+            keys = tuple(inst.keys)
 
             def defines_all(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                get = v.get_hashed
-                for h, k in pairs:
-                    if get(h, k, _MISS) is _MISS:
+                for k in keys:
+                    if k not in v:
                         return False
                 return True
 
             return defines_all
         if op is OpCode.PROPERTY_DEPENDENCIES:
-            deps = tuple(
-                (h, k, tuple(zip(dh, dk)))
-                for k, h, dk, dh in inst.dependencies
-            )
+            deps = tuple((k, tuple(dk)) for k, _h, dk, _dh in inst.dependencies)
 
             def prop_deps(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                get = v.get_hashed
-                for h, k, reqs in deps:
-                    if get(h, k, _MISS) is not _MISS:
-                        for dh, dk in reqs:
-                            if get(dh, dk, _MISS) is _MISS:
+                for k, reqs in deps:
+                    if k in v:
+                        for dk in reqs:
+                            if dk not in v:
                                 return False
                 return True
 
             return prop_deps
         if op is OpCode.OBJECT_SIZE_GREATER:
             b = inst.bound
-            return lambda v: type(v) is not HashedObject or len(v.entries) >= b
+            return lambda v: (
+                (type(v) is not dict and not isinstance(v, dict)) or len(v) >= b
+            )
         if op is OpCode.OBJECT_SIZE_LESS:
             b = inst.bound
-            return lambda v: type(v) is not HashedObject or len(v.entries) <= b
+            return lambda v: (
+                (type(v) is not dict and not isinstance(v, dict)) or len(v) <= b
+            )
         if op is OpCode.PROPERTY_TYPE:
-            k, h = inst.key, inst.key_hash
+            k = inst.key
             tcheck = _type_check(inst.type)
 
             def prop_type(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                child = v.get_hashed(h, k, _MISS)
+                child = v.get(k, _MISS)
                 return child is not _MISS and tcheck(child)
 
             return prop_type
@@ -336,7 +343,7 @@ class _Codegen:
         if op is OpCode.UNIQUE:
 
             def unique(v):
-                if type(v) is not list:
+                if type(v) is not list and not isinstance(v, list):
                     return True
                 seen = set()
                 for item in v:
@@ -349,15 +356,23 @@ class _Codegen:
             return unique
         if op is OpCode.ARRAY_SIZE_GREATER:
             b = inst.bound
-            return lambda v: type(v) is not list or len(v) >= b
+            return lambda v: (
+                (type(v) is not list and not isinstance(v, list)) or len(v) >= b
+            )
         if op is OpCode.ARRAY_SIZE_LESS:
             b = inst.bound
-            return lambda v: type(v) is not list or len(v) <= b
+            return lambda v: (
+                (type(v) is not list and not isinstance(v, list)) or len(v) <= b
+            )
         if op is OpCode.ARRAY_BOUNDS:
             lo, hi = inst.min_len, inst.max_len
             if hi is None:
-                return lambda v: type(v) is not list or len(v) >= lo
-            return lambda v: type(v) is not list or lo <= len(v) <= hi
+                return lambda v: (
+                    (type(v) is not list and not isinstance(v, list)) or len(v) >= lo
+                )
+            return lambda v: (
+                (type(v) is not list and not isinstance(v, list)) or lo <= len(v) <= hi
+            )
 
         if op is OpCode.GREATER:
             b = inst.bound
@@ -412,9 +427,9 @@ class _Codegen:
             child = self.group(inst.children)
 
             def loop_keys(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                for _, key, _val in v.entries:
+                for key in v:
                     if not child(key):
                         return False
                 return True
@@ -424,9 +439,9 @@ class _Codegen:
             child = self.group(inst.children)
 
             def loop_props(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                for _, _, val in v.entries:
+                for val in v.values():
                     if not child(val):
                         return False
                 return True
@@ -434,17 +449,14 @@ class _Codegen:
             return loop_props
         if op is OpCode.LOOP_PROPERTIES_EXCEPT:
             child = self.group(inst.children)
-            excl: Dict[int, List[str]] = {}
-            for k, h in zip(inst.exclude_keys, inst.exclude_hashes):
-                excl.setdefault(h, []).append(k)
+            excl = frozenset(inst.exclude_keys)
             plans = inst.exclude_patterns
 
             def loop_except(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                for h, key, val in v.entries:
-                    cands = excl.get(h)
-                    if cands is not None and (is_short_hash(h) or key in cands):
+                for key, val in v.items():
+                    if key in excl:
                         continue
                     if plans and any(p.matches(key) for p in plans):
                         continue
@@ -458,35 +470,26 @@ class _Codegen:
             plan = inst.plan
 
             def loop_regex(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                for _, key, val in v.entries:
+                for key, val in v.items():
                     if plan.matches(key) and not child(val):
                         return False
                 return True
 
             return loop_regex
         if op in (OpCode.LOOP_PROPERTIES_MATCH, OpCode.LOOP_PROPERTIES_MATCH_CLOSED):
-            table: Dict[int, List[Tuple[str, Check]]] = {}
-            for key, h, grp in inst.matches:
-                table.setdefault(h, []).append((key, self.group(grp)))
+            table: Dict[str, Check] = {}
+            for key, _h, grp in inst.matches:
+                table.setdefault(key, self.group(grp))  # first entry wins
             closed = op is OpCode.LOOP_PROPERTIES_MATCH_CLOSED
             plans = getattr(inst, "tolerate_patterns", ())
 
             def loop_match(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
-                for h, key, val in v.entries:
-                    cands = table.get(h)
-                    fn = None
-                    if cands is not None:
-                        if is_short_hash(h):
-                            fn = cands[0][1]
-                        else:
-                            for k2, f2 in cands:
-                                if k2 == key:
-                                    fn = f2
-                                    break
+                for key, val in v.items():
+                    fn = table.get(key)
                     if fn is None:
                         if closed:
                             if plans and any(p.matches(key) for p in plans):
@@ -502,7 +505,7 @@ class _Codegen:
             child = self.group(inst.children)
 
             def loop_items(v):
-                if type(v) is not list:
+                if type(v) is not list and not isinstance(v, list):
                     return True
                 for item in v:
                     if not child(item):
@@ -515,7 +518,7 @@ class _Codegen:
             start = inst.start
 
             def loop_items_from(v):
-                if type(v) is not list:
+                if type(v) is not list and not isinstance(v, list):
                     return True
                 for i in range(start, len(v)):
                     if not child(v[i]):
@@ -528,7 +531,7 @@ class _Codegen:
             lo, hi = inst.min_count, inst.max_count
 
             def loop_contains(v):
-                if type(v) is not list:
+                if type(v) is not list and not isinstance(v, list):
                     return True
                 count = 0
                 for item in v:
@@ -545,7 +548,7 @@ class _Codegen:
             groups = tuple(self.group(g) for g in inst.groups)
 
             def array_prefix(v):
-                if type(v) is not list:
+                if type(v) is not list and not isinstance(v, list):
                     return True
                 for i, fn in enumerate(groups):
                     if i >= len(v):
@@ -565,7 +568,7 @@ class _Codegen:
             )
 
             def uneval_props(v):
-                if type(v) is not HashedObject:
+                if type(v) is not dict and not isinstance(v, dict):
                     return True
                 names = set(static_keys)
                 plans = list(static_plans)
@@ -575,7 +578,7 @@ class _Codegen:
                             return True
                         names |= keys
                         plans.extend(pats)
-                for _, key, val in v.entries:
+                for key, val in v.items():
                     if key in names or any(p.matches(key) for p in plans):
                         continue
                     if not child(val):
@@ -596,7 +599,7 @@ class _Codegen:
             static_prefix = inst.static_prefix
 
             def uneval_items(v):
-                if type(v) is not list:
+                if type(v) is not list and not isinstance(v, list):
                     return True
                 prefix = static_prefix
                 for guard, bp, sees_all in branches:
@@ -655,21 +658,29 @@ class _Codegen:
             child = self.group(inst.children)
             return lambda v: child(v) if tcheck(v) else True
         if op is OpCode.WHEN_DEFINES:
-            k, h = inst.key, inst.key_hash
+            k = inst.key
             child = self.group(inst.children)
             return (
                 lambda v: child(v)
-                if type(v) is HashedObject and v.get_hashed(h, k, _MISS) is not _MISS
+                if (type(v) is dict or isinstance(v, dict)) and k in v
                 else True
             )
         if op is OpCode.WHEN_ARRAY_SIZE_GREATER:
             b = inst.bound
             child = self.group(inst.children)
-            return lambda v: child(v) if type(v) is list and len(v) > b else True
+            return (
+                lambda v: child(v)
+                if (type(v) is list or isinstance(v, list)) and len(v) > b
+                else True
+            )
         if op is OpCode.WHEN_ARRAY_SIZE_EQUAL:
             b = inst.bound
             child = self.group(inst.children)
-            return lambda v: child(v) if type(v) is list and len(v) == b else True
+            return (
+                lambda v: child(v)
+                if (type(v) is list or isinstance(v, list)) and len(v) == b
+                else True
+            )
 
         # ---- control -------------------------------------------------------------
         if op is OpCode.CONTROL_LABEL:

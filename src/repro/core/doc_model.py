@@ -13,7 +13,14 @@ from typing import Any, Iterator, List, Optional, Tuple
 
 from .hashing import is_short_hash, shash
 
-__all__ = ["HashedObject", "parse_document", "json_type", "json_equal", "canonical"]
+__all__ = [
+    "HashedObject",
+    "parse_document",
+    "nesting_exceeds",
+    "json_type",
+    "json_equal",
+    "canonical",
+]
 
 _MISS = object()
 
@@ -82,6 +89,33 @@ def parse_document(value: Any) -> Any:
     if isinstance(value, list):
         return [parse_document(v) for v in value]
     return value
+
+
+_SCALARS = frozenset((str, int, float, bool, type(None)))
+
+
+def nesting_exceeds(value: Any, limit: int) -> bool:
+    """Whether ``value`` nests objects and arrays more than ``limit`` deep.
+
+    Counts what :func:`parse_document` recurses into (any ``dict`` or
+    ``list``), one level at a time with no recursion, no hashing and no
+    copy: the codegen engine reads the decoded tree unparsed and checks
+    it against the stack bound the parse's recursion would hit.
+    """
+    level = [value] if isinstance(value, (dict, list)) else []
+    depth = 0
+    while level:
+        depth += 1
+        if depth > limit:
+            return True
+        nxt = []
+        push = nxt.append
+        for node in level:
+            for child in node.values() if isinstance(node, dict) else node:
+                if type(child) not in _SCALARS and isinstance(child, (dict, list)):
+                    push(child)
+        level = nxt
+    return False
 
 
 # ---------------------------------------------------------------------------

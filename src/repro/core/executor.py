@@ -21,6 +21,7 @@ for the §6.2.3 hash ablation.
 
 from __future__ import annotations
 
+import sys
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .compiler import CompiledSchema
@@ -29,6 +30,7 @@ from .doc_model import (
     canonical,
     has_type,
     json_equal,
+    nesting_exceeds,
     parse_document,
 )
 from .instructions import Instruction, Instructions, OpCode
@@ -128,16 +130,19 @@ class Validator:
     def is_valid(self, document: Any, *, parsed: bool = False) -> bool:
         """Validate a document (a plain parsed-JSON value by default).
 
-        Deeply nested documents raise a structured
+        The interpreter reads :func:`~repro.core.doc_model.parse_document`'s
+        model (``parsed=True``: the document already is one); the codegen
+        closures read the decoded JSON tree itself, unparsed.  Deeply
+        nested documents raise a structured
         :class:`~repro.core.outcomes.DocumentDepthError` instead of an
         interpreter ``RecursionError`` (the same explicit bound the naive
         interpreter enforces at ``core/interpreter.py``) -- callers on
         the serving path convert it into a reject-with-reason.
         """
         try:
-            doc = document if parsed else parse_document(document)
             if self._fn is not None:
-                return self._fn(doc)
+                return self._fn(self._decoded(document, parsed))
+            doc = document if parsed else parse_document(document)
             return _eval_group(self.compiled.instructions, doc, self.ctx)
         except RecursionError:
             raise DocumentDepthError(
@@ -155,21 +160,27 @@ class Validator:
         Raises :class:`~repro.core.outcomes.ValidationTimeout` when the
         document exhausts the budget's instruction steps, evaluation
         depth, or wall-clock deadline, and
-        :class:`~repro.core.outcomes.DocumentDepthError` when parsing
-        itself over-recurses -- depth bombs and pathological ``pattern``
-        backtracking become structured rejects instead of a stalled
-        engine.  Runs the validator's own engine under one meter: the
-        codegen closures (the serving fallback's engine) meter exactly as
-        the interpreter does -- the same steps, deadline cadence, depth
-        bound and regex refusals (DESIGN.md §11).
+        :class:`~repro.core.outcomes.DocumentDepthError` when the document
+        nests deeper than the interpreter's parse can recurse -- depth
+        bombs and pathological ``pattern`` backtracking become structured
+        rejects instead of a stalled engine.  Runs the validator's own
+        engine under one meter: the codegen closures (the serving
+        fallback's engine) read the decoded tree with no parse, and meter
+        exactly as the interpreter does -- the same steps, deadline
+        cadence, depth bound and regex refusals (DESIGN.md §11).
         """
         budget.check_deadline()
-        try:
-            doc = document if parsed else parse_document(document)
-        except RecursionError:
-            raise DocumentDepthError(
-                "document nesting exceeds the parse stack"
-            ) from None
+        if self._fn is not None:
+            doc = self._decoded(document, parsed)
+        elif parsed:
+            doc = document
+        else:
+            try:
+                doc = parse_document(document)
+            except RecursionError:
+                raise DocumentDepthError(
+                    "document nesting exceeds the parse stack"
+                ) from None
         self.ctx.budget = budget
         try:
             if self._fn is not None:
@@ -181,6 +192,19 @@ class Validator:
             ) from None
         finally:
             self.ctx.budget = None
+
+    @staticmethod
+    def _decoded(document: Any, parsed: bool) -> Any:
+        """The codegen closures' document: the decoded tree as it is, held
+        to the nesting bound the interpreter's parse recursion enforces."""
+        if parsed:
+            raise ValueError(
+                "the codegen engine reads the decoded JSON tree, "
+                "not parse_document's model"
+            )
+        if nesting_exceeds(document, sys.getrecursionlimit()):
+            raise DocumentDepthError("document nesting exceeds the parse stack")
+        return document
 
     def explain(self, document: Any, *, parsed: bool = False):
         """Diagnostic validation (paper §8's error-message option).
